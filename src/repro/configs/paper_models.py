@@ -1,8 +1,10 @@
 """The paper's own evaluation models (§4.1): OPT-1.3B..30B + LLaMA2-7B.
 
 OPT: LayerNorm + GELU FFN + learned positions (use_rope=False).
-Used by the NVLLM simulator (analytical weight/compute accounting) and, in
-reduced form, by examples/edge_serve.py.
+Used by the NVLLM simulator (analytical weight/compute accounting), served
+at published widths by ``launch/serve.py --arch opt-1.3b`` (and by
+``chip_smoke.py``), and in reduced form (``opt-tiny``) by the examples and
+the CPU tests.
 """
 from repro.configs.base import ArchConfig
 
@@ -36,3 +38,6 @@ OPT_TINY = ArchConfig(
     n_kv_heads=4, head_dim=32, d_ff=512, vocab_size=512, norm_type="layer",
     ffn_type="gelu", use_rope=False, max_seq=512,
 )
+
+# Served by name through ``launch/serve.py --arch <name>``.
+PAPER_MODELS = {c.name: c for c in (*OPT_FAMILY, LLAMA2_7B, OPT_TINY)}

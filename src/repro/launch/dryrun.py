@@ -1,9 +1,12 @@
 """Multi-pod dry-run (deliverable e): lower + compile every cell.
 
-MUST be the very first two lines — before ANY other import — since jax
-locks the device count on first init:
+The dry-run lowers onto 512 VIRTUAL CPU devices, so it pins itself to the
+CPU platform (on a machine with an accelerator it must not claim the chip)
+and forces the device count. Both MUST come before ANY other import —
+jax locks the platform and device count on first init:
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 
@@ -130,7 +133,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         mesh = make_production_mesh(multi_pod=multi_pod)
         step, args, in_sh, out_sh, donate = build_cell(
             arch, shape_name, mesh, smoke=smoke)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
                              donate_argnums=donate)
             lowered = jitted.lower(*args)
@@ -139,9 +142,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             t_compile = time.time() - t0 - t_lower
             ma = compiled.memory_analysis()
             xla_cost = compiled.cost_analysis() or {}
-            if isinstance(xla_cost, (list, tuple)):
-                # jax <= 0.4.x returns a one-element list of dicts
-                xla_cost = xla_cost[0] if xla_cost else {}
             text = compiled.as_text()
         cost = hlo_cost.analyze(text)       # trip-count-aware (launch/hlo_cost)
         n_chips = mesh.devices.size

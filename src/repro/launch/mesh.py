@@ -13,12 +13,8 @@ import jax
 
 
 def _mk(shape, axes):
-    # jax.sharding.AxisType only exists in jax >= 0.5; the pinned 0.4.x
-    # meshes are implicitly Auto-typed, so just omit the kwarg there.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

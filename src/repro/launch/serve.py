@@ -1,7 +1,12 @@
 """Serving driver: tiered NVLLM deployment + continuous batching + Alg. 2.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch granite-8b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch opt-1.3b \
         --requests 6 --max-new 12 --rber 1e-4
+
+``--arch`` names one of the paper's models at published widths
+(``opt-1.3b`` .. ``opt-30b``, ``llama2-7b``; ``opt-tiny`` is the reduced
+OPT the CPU tests use) or a registry arch (``configs/__init__.py``), which
+``--smoke`` swaps for its reduced config.
 
 Deploys the model into the tiered INT8+ECC form, spins the engine with a
 stream of synthetic requests, and reports tokens/s plus the KV-cache-aware
@@ -29,20 +34,57 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
 import threading
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
 
-from repro.configs import get_config
-from repro.configs.paper_models import OPT_TINY
+from repro.configs import ARCHS, get_config
+from repro.configs.paper_models import PAPER_MODELS
 from repro.models import family_module
 from repro.serving.engine import Engine
 from repro.serving.sampler import SampleConfig
 
+REPO_ROOT = Path(__file__).resolve().parents[3]
 
-def build_engine(arch: str = "opt-tiny", smoke: bool = True,
+
+def resolve_config(arch: str, smoke: bool = False):
+    """``arch`` by name: a paper model (always at its published widths) or
+    a registry arch (its reduced config with ``smoke``)."""
+    if arch in PAPER_MODELS:
+        if smoke and arch != "opt-tiny":
+            raise SystemExit(f"--smoke reduces registry archs; {arch} is "
+                             "served at published widths (opt-tiny is the "
+                             "reduced OPT)")
+        return PAPER_MODELS[arch]
+    if arch not in ARCHS:
+        raise SystemExit(f"unknown arch {arch!r}; paper models: "
+                         f"{sorted(PAPER_MODELS)}, registry: {list(ARCHS)}")
+    return get_config(arch, smoke=smoke)
+
+
+def enable_compile_cache(root: Path = REPO_ROOT) -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory. ``JAX_COMPILATION_CACHE_DIR`` wins when set (jax
+    reads it itself); otherwise one fixed, git-ignored path in the
+    checkout — the path is part of the cache key, so it must not move."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(root / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_label() -> str:
+    """The device the process serves on, as JAX reports it."""
+    dev = jax.devices()[0]
+    return f"{dev.platform} ({dev.device_kind}) x{len(jax.devices())}"
+
+
+def build_engine(arch: str = "opt-tiny", smoke: bool = False,
                  rber: float = 0.0, seed: int = 0, kv_aware: bool = True,
                  stream: bool = False,
                  device_budget_mib: float | None = None,
@@ -53,14 +95,15 @@ def build_engine(arch: str = "opt-tiny", smoke: bool = True,
                  shards: int = 1, prefix_cache: bool = False,
                  max_waiting: int | None = None,
                  sample_cfg: SampleConfig | None = None,
-                 fault_cfg=None) -> Engine:
+                 admission_cfg=None, fault_cfg=None) -> Engine:
     """Deploy ``arch`` into the tiered form and construct the serving
     engine — shared by the burst driver (``serve``) and the HTTP
     frontend (``--serve-http``). ``fault_cfg`` (a store.faults
     FaultConfig) arms read-time NAND fault injection on the streamed
     page store — attached AFTER programming, so program-time rber and
-    injected read faults compose (DESIGN.md §13)."""
-    cfg = OPT_TINY if arch == "opt-tiny" else get_config(arch, smoke=smoke)
+    injected read faults compose (DESIGN.md §13). ``admission_cfg`` (a
+    core.scheduler AdmissionConfig) sets chunk width and token budget."""
+    cfg = resolve_config(arch, smoke)
     if cfg.family not in ("dense", "moe"):
         raise SystemExit("engine serves dense- and moe-family archs")
     mod = family_module(cfg.family)
@@ -133,7 +176,7 @@ def build_engine(arch: str = "opt-tiny", smoke: bool = True,
                  weight_store=store, stream_cfg=stream_cfg,
                  spec_cfg=spec_cfg, draft_cfg=draft_cfg,
                  draft_params=draft_params, prefix_cache=prefix_cache,
-                 max_waiting=max_waiting)
+                 max_waiting=max_waiting, admission_cfg=admission_cfg)
     if fault_cfg is not None:
         if not eng.streamed:
             raise SystemExit("--fault-* injects read-time NAND faults: "
@@ -161,7 +204,7 @@ def _start_stats_logger(line_fn, interval_s: float) -> threading.Event:
     return stop
 
 
-def serve(arch: str = "opt-tiny", smoke: bool = True, n_requests: int = 6,
+def serve(arch: str = "opt-tiny", smoke: bool = False, n_requests: int = 6,
           max_new: int = 12, rber: float = 0.0, seed: int = 0,
           kv_aware: bool = True, stream: bool = False,
           device_budget_mib: float | None = None,
@@ -170,22 +213,32 @@ def serve(arch: str = "opt-tiny", smoke: bool = True, n_requests: int = 6,
           adaptive_k: bool = False,
           store_image: str | None = None, ckpt: str | None = None,
           shards: int = 1, fault_cfg=None,
-          stats_interval: float = 0.0) -> dict:
+          stats_interval: float = 0.0,
+          prompts: list[list[int]] | None = None,
+          sample_cfg: SampleConfig | None = None,
+          admission_cfg=None) -> dict:
+    """Serve one burst of requests to completion. ``prompts`` replaces
+    the ``n_requests`` seeded random prompts (3-9 tokens each); outputs
+    are keyed by submit order."""
     eng = build_engine(arch, smoke=smoke, rber=rber, seed=seed,
                        kv_aware=kv_aware, stream=stream,
                        device_budget_mib=device_budget_mib,
                        group_size=group_size, auto_depth=auto_depth,
                        spec_k=spec_k, drafter=drafter,
                        adaptive_k=adaptive_k, store_image=store_image,
-                       ckpt=ckpt, shards=shards, fault_cfg=fault_cfg)
+                       ckpt=ckpt, shards=shards, fault_cfg=fault_cfg,
+                       sample_cfg=sample_cfg, admission_cfg=admission_cfg)
     cfg = eng.cfg
-    rng = np.random.default_rng(seed)
+    if prompts is None:
+        rng = np.random.default_rng(seed)
+        prompts = [rng.integers(1, cfg.vocab_size,
+                                rng.integers(3, 10)).tolist()
+                   for _ in range(n_requests)]
     # submit enqueues: the whole burst goes in up front and the engine's
     # waiting->running queue admits as slots/blocks free up (no host-side
     # slot polling; oversubscription is the normal case).
     first_tok: dict[int, int] = {}
-    for _ in range(n_requests):
-        prompt = rng.integers(1, cfg.vocab_size, rng.integers(3, 10)).tolist()
+    for prompt in prompts:
         eng.submit(prompt, max_new=max_new)
     t0 = time.time()
     n_processed = n_steps = 0
@@ -248,7 +301,7 @@ def serve_http(port: int, arch: str = "opt-tiny", prefix_cache: bool = True,
     front = ServeFront(eng, max_waiting=max_waiting, fault_policy=policy)
     server = make_http_server(front, port)
     host, bound = server.server_address[:2]
-    print(f"serving {arch} on http://{host}:{bound} "
+    print(f"serving {arch} on {device_label()} at http://{host}:{bound} "
           f"(POST /v1/generate, GET /v1/stats, GET /v1/health, "
           f"GET /v1/metrics; "
           f"prefix_cache={'on' if prefix_cache else 'off'}, "
@@ -280,8 +333,11 @@ def serve_http(port: int, arch: str = "opt-tiny", prefix_cache: bool = True,
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--arch", default="opt-tiny")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--arch", default="opt-tiny",
+                    help="paper model (opt-1.3b..opt-30b, llama2-7b, "
+                         "opt-tiny) or registry arch")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve a registry arch's reduced config")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=12)
     # None = mode default: 1e-4 normally, 0 with --store-image (injection
@@ -361,6 +417,7 @@ def main():
                     help="ObsPlane: print one structured 'stats {json}' "
                          "line every S seconds (0 = off)")
     args = ap.parse_args()
+    enable_compile_cache()
     rber = args.rber
     if rber is None:
         rber = 0.0 if args.store_image else 1e-4
@@ -411,8 +468,8 @@ def main():
                   f"(load in Perfetto / chrome://tracing)")
     print(f"served {len(out['outputs'])} requests, {out['tokens']} generated "
           f"tokens in {out['seconds']:.1f}s ({out['tps']:.1f} generated "
-          f"tok/s, {out['processed_tps']:.1f} processed tok/s on CPU), "
-          f"step traces={out['traces']}")
+          f"tok/s, {out['processed_tps']:.1f} processed tok/s on "
+          f"{device_label()}), step traces={out['traces']}")
     if "experts" in out:
         ex = out["experts"]
         print(f"expert paging: {ex['expert_hit_rate']*100:.0f}% cache hit "

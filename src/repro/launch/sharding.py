@@ -37,23 +37,6 @@ log = logging.getLogger("repro.sharding")
 MODEL = "model"
 
 
-def get_abstract_mesh():
-    """Guarded ``jax.sharding.get_abstract_mesh``.
-
-    The accessor only exists in jax >= 0.5; on the pinned 0.4.x it is absent
-    and the only mesh context is the thread-local physical mesh. Returns the
-    abstract mesh, or ``None`` when the API (or any mesh context) is
-    unavailable — callers treat ``None`` like an empty mesh.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        return None
-    try:
-        return fn()
-    except Exception:                                    # pragma: no cover
-        return None
-
-
 # --- divisibility guard -----------------------------------------------------
 
 
@@ -295,19 +278,13 @@ def opt_state_specs(opt_state, pspecs, mesh, zero1: bool = False):
 def data_group_count(n_tokens: int) -> int:
     """Size of the data-parallel axis group for hierarchical MoE dispatch
     (1 outside a mesh context). Halved until it divides ``n_tokens``."""
-    try:
-        from jax._src import mesh as mesh_lib
-        env_mesh = mesh_lib.thread_resources.env.physical_mesh
-        if env_mesh.empty:
-            env_mesh = get_abstract_mesh()
-        if env_mesh is None or env_mesh.empty:
-            return 1
-        g = 1
-        for a in ("pod", "data"):
-            if a in env_mesh.axis_names:
-                g *= env_mesh.shape[a]
-    except Exception:                                    # pragma: no cover
+    env_mesh = jax.sharding.get_abstract_mesh()
+    if env_mesh.empty:
         return 1
+    g = 1
+    for a in ("pod", "data"):
+        if a in env_mesh.axis_names:
+            g *= env_mesh.shape[a]
     while g > 1 and n_tokens % g:
         g //= 2
     return max(g, 1)
@@ -346,18 +323,12 @@ pin_grad.defvjp(_pin_grad_fwd, _pin_grad_bwd)
 
 def constrain(x, *spec):
     """with_sharding_constraint that degrades to identity outside a mesh
-    context and respects the divisibility guard. Models call this to hint
-    activation sharding (e.g. MoE dispatch buffers) without knowing the mesh.
+    context (``jax.set_mesh``) and respects the divisibility guard. Models
+    call this to hint activation sharding (e.g. MoE dispatch buffers)
+    without knowing the mesh.
     """
-    try:
-        from jax._src import mesh as mesh_lib
-        env_mesh = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:                                    # pragma: no cover
-        return x
+    env_mesh = jax.sharding.get_abstract_mesh()
     if env_mesh.empty:
-        abstract = get_abstract_mesh()
-        if abstract is None or abstract.empty:
-            return x
-        env_mesh = abstract
+        return x
     p = guard(x.shape, P(*spec), env_mesh, "constraint")
     return jax.lax.with_sharding_constraint(x, p)

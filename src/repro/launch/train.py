@@ -73,7 +73,7 @@ def train(arch: str, smoke: bool = True, steps: int = 20, batch: int = 8,
     opt_state = jax.device_put(opt_state, named_o)
 
     step_fn = make_train_step(cfg, opt, n_micro=n_micro)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step_fn, in_shardings=(named_p, named_o, None),
                          donate_argnums=(0, 1))
 
@@ -108,7 +108,7 @@ def train(arch: str, smoke: bool = True, steps: int = 20, batch: int = 8,
         dstep, host_batch = prefetch.next()
         assert dstep == step, (dstep, step)
         batch_dict = _to_batch(cfg, host_batch, seq, cfg.d_model)
-        with mesh:
+        with jax.set_mesh(mesh):
             params, opt_state, metrics = executor.run_step(
                 step, params, opt_state, batch_dict)
         losses.append(float(metrics["loss"]))

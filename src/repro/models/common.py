@@ -45,14 +45,8 @@ def pin_layer_grads(lp):
     """
     import jax.tree_util as jtu
     from repro.launch import sharding as sh
-    try:
-        from jax._src import mesh as mesh_lib
-        env_mesh = mesh_lib.thread_resources.env.physical_mesh
-        if env_mesh.empty:
-            env_mesh = sh.get_abstract_mesh()
-        if env_mesh is None or env_mesh.empty:
-            return lp
-    except Exception:                                    # pragma: no cover
+    env_mesh = jax.sharding.get_abstract_mesh()
+    if env_mesh.empty:
         return lp
 
     def one(path, w):
@@ -62,20 +56,6 @@ def pin_layer_grads(lp):
         return sh.pin_grad(w, tuple(spec))
 
     return jtu.tree_map_with_path(one, lp)
-
-
-@jax.custom_jvp
-def _barrier(x):
-    """optimization_barrier with a differentiation rule: the pinned jax
-    0.4.37 defines none for the primitive, which would fail every training
-    backward. The barrier is an identity, so the tangent passes through
-    (the cotangent stash the primal barrier protects is unaffected)."""
-    return jax.lax.optimization_barrier(x)
-
-
-@_barrier.defjvp
-def _barrier_jvp(primals, tangents):
-    return _barrier(primals[0]), tangents[0]
 
 
 def pin_batch(x):
@@ -91,7 +71,7 @@ def pin_batch(x):
     # The barrier stops XLA from sinking the rms_norm f32 upcast into the
     # layer-scan stash, which would store the carry TWICE (bf16 + f32):
     # measured -33.8 GB/chip on llama3-405b train_4k (EXPERIMENTS.md §Perf).
-    x = _barrier(x)
+    x = jax.lax.optimization_barrier(x)
     if SEQ_SHARD_RESIDUAL and x.ndim >= 3 and x.shape[1] > 1:
         return constrain(x, ("pod", "data"), "model",
                          *([None] * (x.ndim - 2)))

@@ -59,11 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:                                     # jax >= 0.5 moved shard_map
-    from jax.experimental.shard_map import shard_map
-except ImportError:                      # pragma: no cover
-    from jax.shard_map import shard_map
-
 from repro import obs
 from repro.core import scheduler as sched
 from repro.core.erdpe import ExecMode, flash_matmul
@@ -705,6 +700,13 @@ class Engine:
         self._next_rid = 0
         self._key = jax.random.PRNGKey(seed)
         self._prev_cycles = jnp.int32(0)
+        # sharded planes return the serving state replicated over the
+        # mesh: start it that way, or step 2 retraces every jit for the
+        # changed input sharding
+        self.pool.set_device_state(
+            self._put_replicated(self.pool.device_state()))
+        self.bitmap, self._prev_cycles = self._put_replicated(
+            (self.bitmap, self._prev_cycles))
         self._npu_frac = 1.0             # host view of the Alg. 2 bitmap
         self._stall_frac = 0.0           # EMA of streamer stall per step
         self._steps_done = 0
@@ -1387,11 +1389,11 @@ class Engine:
             # group args: (layers_dram, window, pool_buf, k, v, x,
             # positions, ctx_lens, block_tables, bitmap, lo) — the pool
             # buffer (index 2) is the only sharded operand.
-            group = shard_map(
+            group = jax.shard_map(
                 functools.partial(group, axis_name=MODEL_AXIS),
                 mesh=self.mesh,
                 in_specs=(rspec, rspec, pspec) + (rspec,) * 8,
-                out_specs=rspec, check_rep=False)
+                out_specs=rspec, check_vma=False)
             jit_kw = {"out_shardings": NamedSharding(self.mesh, P())}
 
         def group_fn(*args):
@@ -1504,17 +1506,17 @@ class Engine:
             # slab, slab_map, pool_buf, k_new, v_new, q_lens, admitted,
             # positions, block_tables, key[, drafts, n_draft, is_decode])
             # — the pool buffer is the only sharded operand of either.
-            fused = shard_map(
+            fused = jax.shard_map(
                 functools.partial(fused, axis_name=MODEL_AXIS),
                 mesh=self.mesh,
                 in_specs=(rspec,) * 9 + (pspec,) + (rspec,) * 4,
-                out_specs=rspec, check_rep=False)
-            tail = shard_map(
+                out_specs=rspec, check_vma=False)
+            tail = jax.shard_map(
                 functools.partial(tail, axis_name=MODEL_AXIS),
                 mesh=self.mesh,
                 in_specs=(rspec,) * 9 + (pspec,)
                 + (rspec,) * (7 + n_extra),
-                out_specs=rspec, check_rep=False)
+                out_specs=rspec, check_vma=False)
             jit_kw = {"out_shardings": NamedSharding(self.mesh, P())}
 
         def head_fn(*args):

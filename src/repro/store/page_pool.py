@@ -49,14 +49,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.registry import Sample
-from repro.obs.trace import TID_POOL, default_tracer
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:                                      # moved out of experimental in 0.6
-    from jax.experimental.shard_map import shard_map
-except ImportError:                       # pragma: no cover
-    from jax.shard_map import shard_map
+from repro.obs.registry import Sample
+from repro.obs.trace import TID_POOL, default_tracer
 
 # In-place page scatter for donate=True pools: donating the buffer lets
 # XLA write only the new rows (measured ~170x cheaper than the functional
@@ -67,24 +63,23 @@ _scatter_donate = jax.jit(lambda buf, slots, pages: buf.at[slots].set(pages),
 
 
 def pinned_host_sharding():
-    """The page-locked host staging target for upload H2D, or None.
+    """The page-locked host staging target for upload H2D, or None on CPU.
 
-    Real accelerators expose a ``pinned_host`` memory space; staging the
-    window there turns the device copy into an async DMA out of locked
-    memory (the classic memcpy-into-pinned + async-H2D pipeline). The CPU
-    backend has no DMA to hide, so the path degrades to a no-op fallback —
-    the plain ``device_put`` the pool always did."""
+    Accelerators expose a ``pinned_host`` memory space; staging the window
+    there turns the device copy into an async DMA out of locked memory
+    (the classic memcpy-into-pinned + async-H2D pipeline). The CPU backend
+    has no DMA to hide, so it keeps the plain ``device_put``. An
+    accelerator WITHOUT the space is an error, not a silent downgrade:
+    the streamed planes' transfer path would quietly change."""
     if jax.default_backend() == "cpu":
         return None
-    try:
-        dev = jax.local_devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-        if "pinned_host" not in kinds:
-            return None
-        return jax.sharding.SingleDeviceSharding(dev,
-                                                 memory_kind="pinned_host")
-    except Exception:                     # old jaxlib without memories API
-        return None
+    dev = jax.local_devices()[0]
+    kinds = {m.kind for m in dev.addressable_memories()}
+    if "pinned_host" not in kinds:
+        raise RuntimeError(
+            f"{dev.device_kind} exposes no pinned_host memory space "
+            f"(has {sorted(kinds)}): cannot arm pinned upload staging")
+    return jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
 
 
 class WeightPagePool:
@@ -107,11 +102,10 @@ class WeightPagePool:
         """Pinned-staging transfer state: a REUSABLE host staging buffer
         (grown geometrically, never shrunk) that ``read_pages`` fills in
         place, bounced through page-locked memory so the device copy is an
-        async DMA. Only armed when a ``pinned_host`` space exists: reusing
-        the buffer is only safe once the bytes have landed in jax-owned
-        pinned memory (the bounce blocks on that host-side memcpy; the
-        H2D out of it stays async). Without one — the CPU backend — the
-        upload path is the unchanged one-shot ``device_put``."""
+        async DMA. Reusing the buffer is only safe once the bytes have
+        landed in jax-owned pinned memory (the bounce blocks on that
+        host-side memcpy; the H2D out of it stays async). On the CPU
+        backend the upload path is the one-shot ``device_put``."""
         self._pinned = pinned_host_sharding()
         self._staging: np.ndarray | None = None
         self.staging_allocs = 0
@@ -124,7 +118,6 @@ class WeightPagePool:
             self.pages_staged = 0
             self.bytes_staged = 0
             self.pinned_uploads = 0
-            self.pinned_fallbacks = 0
 
     def _stage_host(self, n_rows: int) -> np.ndarray:
         """First ``n_rows`` page rows of the reusable staging buffer."""
@@ -144,17 +137,12 @@ class WeightPagePool:
             return jax.device_put(self.store.read_pages(ids).view(np.int8))
         rows = self._stage_host(len(ids))
         staged = self.store.read_pages(ids, out=rows).view(np.int8)
-        try:
-            locked = jax.device_put(staged, self._pinned)
-            locked.block_until_ready()
-            self.pinned_uploads += 1
-            return jax.device_put(locked, jax.local_devices()[0])
-        except Exception:
-            # driver said no (e.g. pinned pool exhausted): disarm for good,
-            # copy out of the reusable rows so nothing aliases them
-            self._pinned = None
-            self.pinned_fallbacks += 1
-            return jax.device_put(staged.copy())
+        locked = jax.device_put(staged, self._pinned)
+        locked.block_until_ready()
+        self.pinned_uploads += 1
+        # the target names its memory kind: a bare device would keep the
+        # source's pinned_host kind and be refused
+        return jax.device_put(locked, self._pinned.with_memory_kind("device"))
 
     # --- allocator -----------------------------------------------------------
 
@@ -294,7 +282,6 @@ class WeightPagePool:
                     "pool_pages_staged": self.pages_staged,
                     "pool_bytes_staged": self.bytes_staged,
                     "pool_pinned_uploads": self.pinned_uploads,
-                    "pool_pinned_fallbacks": self.pinned_fallbacks,
                     "pool_staging_allocs": self.staging_allocs,
                     "pool_grows": self.grows}
 
@@ -364,18 +351,18 @@ class ShardedWeightPagePool(WeightPagePool):
         self.grows = 0
         # per-mesh jits (module-level sharing would leak meshes across tests)
         self._scatter = jax.jit(
-            shard_map(lambda buf, slots, pages: buf.at[slots[0]].set(
+            jax.shard_map(lambda buf, slots, pages: buf.at[slots[0]].set(
                 pages[0]),
                 mesh=mesh,
                 in_specs=(P("model", None), P("model", None),
                           P("model", None, None)),
-                out_specs=P("model", None), check_rep=False),
+                out_specs=P("model", None), check_vma=False),
             donate_argnums=(0,) if self.donate else ())
         self._copy_grow = jax.jit(
-            shard_map(lambda nb, ob: nb.at[:ob.shape[0]].set(ob),
+            jax.shard_map(lambda nb, ob: nb.at[:ob.shape[0]].set(ob),
                       mesh=mesh,
                       in_specs=(P("model", None), P("model", None)),
-                      out_specs=P("model", None), check_rep=False),
+                      out_specs=P("model", None), check_vma=False),
             donate_argnums=(0,))
         self._init_staging()
         self.reset_counters()

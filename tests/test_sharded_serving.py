@@ -171,6 +171,46 @@ def test_staging_buffer_grows_geometrically():
     assert pool.stats()["pool_staging_allocs"] == 2
 
 
+def test_accelerator_without_pinned_space_raises(monkeypatch):
+    """Off the CPU, a device with no pinned_host memory space is an error:
+    the pinned staging path is never silently downgraded."""
+    from repro.store import page_pool
+
+    class Device:
+        device_kind = "accelerator without pinned host memory"
+
+        def addressable_memories(self):
+            return [type("Memory", (), {"kind": "device"})()]
+
+    monkeypatch.setattr(page_pool.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(page_pool.jax, "local_devices", lambda: [Device()])
+    with pytest.raises(RuntimeError, match="no pinned_host memory space"):
+        page_pool.pinned_host_sharding()
+
+
+def test_pinned_staging_upload_reconstructs_pages():
+    """The accelerator transfer path — host rows bounced through a
+    pinned_host buffer, then moved to device memory — armed by hand on
+    the CPU backend, which also exposes a pinned_host space."""
+    from jax.sharding import SingleDeviceSharding
+    store = PageStore(n_planes=4)
+    store.put("w", encode_flash(jnp.ones((128, 256)), rber=1e-3, seed=1))
+    pool = WeightPagePool(store, 2 * store.entry_pages("w"), donate=True)
+    pool._pinned = SingleDeviceSharding(jax.devices()[0],
+                                        memory_kind="pinned_host")
+    for _ in range(2):                    # second upload reuses the rows
+        tbl = pool.upload(["w"])["w"]
+        buf = np.asarray(pool.buffer).astype(np.uint8)
+        got = buf[np.asarray(tbl["q_tbl"]).reshape(-1)]
+        np.testing.assert_array_equal(
+            got, store.read_pages(np.asarray(store.table["w"]["q"].pages)))
+        pool.free(tbl["slots"])
+    st = pool.stats()
+    assert st["pool_pinned_uploads"] == st["pool_uploads"] == 2
+    assert st["pool_staging_allocs"] == 1
+    assert pool.buffer.sharding.memory_kind == "device"
+
+
 def test_cpu_fallback_keeps_upload_correct():
     """On the CPU backend there is no pinned_host space: the pinned
     counter stays zero, the one-shot device_put path serves, and the
@@ -205,10 +245,6 @@ def test_paged_ffn_psum_parity(rber):
     column-parallel (no collective), down row-parallel closed by one psum
     — bit-comparable to the resident ECDP chain under rber+ECC."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:                      # pragma: no cover
-        from jax import shard_map
     from repro.kernels.paged_ffn import paged_ecdp_matmul_xla
 
     k, dff = 128, 512
@@ -239,9 +275,9 @@ def test_paged_ffn_psum_parity(rber):
                                      axis_name="model")
 
     x = jax.random.normal(jax.random.PRNGKey(2), (4, k), jnp.float32)
-    fn = jax.jit(shard_map(body, mesh=mesh,
-                           in_specs=(P(), P("model", None)),
-                           out_specs=P(), check_rep=False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                               in_specs=(P(), P("model", None)),
+                               out_specs=P(), check_vma=False))
     out = pool.dispatch(lambda buf: fn(x, buf))
     want = _tp_ffn_reference(x, gfw, dfw)
     # per-shard partials are bit-exact (int8 + ECC corrections are local);

@@ -1,0 +1,139 @@
+"""Ahead-of-time compiles of the main-path kernels for a described TPU v5e
+chip at OPT-1.3B widths (FFN K=2048, N=8192, M=8; attention B=4, KV=32,
+Dh=64, 16-token blocks). No chip is needed: the TPU compiler compiles for
+a topology that is described, not attached, and refuses what the chip
+would refuse — which interpret-mode tests cannot show.
+
+Kernels the compiler refuses today are strict xfails that name the error,
+so a fix (an XPASS) or a different refusal fails the suite."""
+from __future__ import annotations
+
+import functools
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import ops
+from repro.kernels.decode_attn import decode_attn_pallas
+from repro.kernels.ecdp import ecdp_matmul_pallas
+from repro.kernels.paged_attn import paged_attn_pallas
+from repro.kernels.paged_ffn import (TILE, paged_ecdp_matmul_pallas,
+                                     paged_ecdp_matmul_xla)
+from repro.store.pagestore import PAGE_BYTES
+
+M, K, N = 8, 2048, 8192                         # OPT-1.3B FFN up-projection
+B, KV, DH, BLOCK, MAX_SEQ, T = 4, 32, 64, 16, 256, 16
+KT, NT = K // TILE, N // TILE
+N_PAGES = 2048                                  # >= q + parity + scale pages
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                       # noqa: BLE001 - skip cause
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _ffn_paged(ecc_enabled):
+    return (functools.partial(paged_ecdp_matmul_xla, kn=(K, N),
+                              ecc_enabled=ecc_enabled),
+            [((M, K), jnp.float32), ((N_PAGES, PAGE_BYTES), jnp.int8),
+             ((KT, NT), jnp.int32), ((K // 8 * N // PAGE_BYTES,), jnp.int32),
+             ((-(-4 * N // PAGE_BYTES),), jnp.int32)])
+
+
+def _ffn_resident(fn, **kw):
+    return (functools.partial(fn, **kw),
+            [((M, K), jnp.float32), ((K, N), jnp.int8),
+             ((K // 8, N), jnp.uint8)]
+            + ([((1, N), jnp.float32)] if fn is ops.ecdp_matmul_xla else []))
+
+
+def _ffn_paged_pallas(ecc_enabled):
+    return (functools.partial(paged_ecdp_matmul_pallas, block_m=M,
+                              ecc_enabled=ecc_enabled, interpret=False),
+            [((M, K), jnp.float32), ((N_PAGES, PAGE_BYTES), jnp.int8),
+             ((KT, NT), jnp.int32), ((K // 8, N), jnp.uint8)])
+
+
+def _paged_attn():
+    n_blocks = B * MAX_SEQ // BLOCK + 1                 # + the dump block
+    pool = ((n_blocks, BLOCK, KV, DH), jnp.bfloat16)
+    return (functools.partial(paged_attn_pallas, interpret=False),
+            [((B, KV, T, DH), jnp.bfloat16), pool, pool,
+             ((B, MAX_SEQ // BLOCK), jnp.int32), ((B,), jnp.int32)])
+
+
+def _decode_attn():
+    pool = ((B, MAX_SEQ, KV, DH), jnp.bfloat16)
+    return (functools.partial(decode_attn_pallas, block_s=MAX_SEQ,
+                              interpret=False),
+            [((B, KV, 1, DH), jnp.bfloat16), pool, pool, ((B,), jnp.int32)])
+
+
+CASES = {
+    "paged_ecdp_matmul_xla_ecc": lambda: _ffn_paged(True),
+    "ecdp_matmul_xla_ecc": lambda: _ffn_resident(ops.ecdp_matmul_xla,
+                                                 ecc_enabled=True),
+    "paged_ecdp_matmul_pallas_no_ecc": lambda: _ffn_paged_pallas(False),
+    "paged_attn_pallas": _paged_attn,
+}
+
+# name -> the compiler's refusal today (a regex on the error text)
+REFUSED = {
+    "paged_ecdp_matmul_pallas_ecc": (
+        lambda: _ffn_paged_pallas(True),
+        "Reductions over unsigned integers not implemented"),
+    "ecdp_matmul_pallas_ecc": (
+        lambda: _ffn_resident(ecdp_matmul_pallas, block_m=M, block_k=512,
+                              block_n=512, ecc_enabled=True,
+                              interpret=False),
+        "Reductions over unsigned integers not implemented"),
+    "decode_attn_pallas": (
+        _decode_attn,
+        "requires that rank 1 block shapes"),
+}
+
+
+class KnownRefusal(Exception):
+    """The compiler refused a kernel with the error named in REFUSED."""
+
+
+def _compile(build, sharding):
+    fn, shapes = build()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_main_path_kernel_compiles_for_v5e(name, one_chip):
+    compiled = _compile(CASES[name], one_chip)
+    if "pallas" in name:
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", [
+    pytest.param(n, marks=pytest.mark.xfail(strict=True, raises=KnownRefusal,
+                                            reason=msg))
+    for n, (_, msg) in sorted(REFUSED.items())])
+def test_kernel_refused_for_v5e(name, one_chip):
+    build, msg = REFUSED[name]
+    try:
+        _compile(build, one_chip)
+    except Exception as e:                       # noqa: BLE001 - classified
+        if re.search(msg, str(e)):
+            raise KnownRefusal(msg) from e
+        raise
