@@ -26,6 +26,7 @@ Semantics (verified by property tests in tests/test_ecc.py):
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -96,6 +97,7 @@ def encode(raw_bytes: jnp.ndarray, phys_mask: jnp.ndarray | None = None) -> jnp.
     return hamming | overall
 
 
+@jax.named_scope("ecc")        # nests under the matmul that reads the weight
 def check_and_correct(
     raw_bytes: jnp.ndarray,
     parity: jnp.ndarray,

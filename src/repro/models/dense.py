@@ -76,6 +76,7 @@ def init(cfg, key) -> dict:
     return params
 
 
+@jax.named_scope("ffn")
 def _ffn_apply(cfg, p, x, axis_name=None):
     if cfg.ffn_type == "swiglu":
         return cm.swiglu_apply(p, x, axis_name=axis_name)

@@ -11,15 +11,15 @@ Perfetto: stacked "X" (complete) events per track, named via "M"
 
 Design points:
 
-  * Disabled by default (``Tracer(enabled=False)``): ``span()`` returns
-    one shared no-op context manager and ``complete()``/``instant()``
-    return immediately — the hot path pays an attribute check.
+  * Disabled by default (``Tracer(enabled=False)``): ``complete()``
+    returns immediately — the hot path pays an attribute check.
+  * The program times its own spans and hands them over pre-timed
+    (``complete()``, a ``perf_counter`` start and a duration): engine step
+    phases also open a ``jax.profiler.TraceAnnotation`` (serving/engine.py),
+    so under a profiler session the same phases land in the device trace's
+    clock, and this module stays free of jax.
   * Bounded: events land in a ``deque(maxlen=...)`` ring, so a
     long-lived server traces the LAST N events, never unbounded memory.
-  * Nesting and orphans: ``span()`` keeps a per-thread stack; Chrome
-    renders containment from timestamps, and ``orphans()`` counts spans
-    begun but never ended (a leak detector for abandoned iterations,
-    tested in tests/test_obs.py).
   * The exported file is a JSON array written ONE EVENT PER LINE — valid
     Chrome/Perfetto trace JSON and line-greppable (the CI schema check
     parses it whole, then validates every event dict).
@@ -52,41 +52,6 @@ _TRACK_NAMES = {
 _REQUEST_TRACKS = 8      # rid % 8 request lanes
 
 
-class _NullSpan:
-    """Shared no-op context manager for the disabled tracer."""
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class _Span:
-    def __init__(self, tracer: "Tracer", name: str, tid: int, cat: str,
-                 args: dict | None):
-        self._tracer = tracer
-        self._name = name
-        self._tid = tid
-        self._cat = cat
-        self._args = args
-
-    def __enter__(self):
-        self._tracer._push(self._name)
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
-        self._tracer._pop(self._name)
-        self._tracer.complete(self._name, self._t0, dur, tid=self._tid,
-                              cat=self._cat, args=self._args)
-        return False
-
-
 class Tracer:
     """Bounded, thread-safe trace-event recorder (one per process by
     default — ``default_tracer()``)."""
@@ -95,68 +60,14 @@ class Tracer:
         self.enabled = bool(enabled)
         self._events: deque = deque(maxlen=int(max_events))
         self._lock = threading.Lock()
-        self._local = threading.local()
-        self._orphans = 0
         # one origin for the whole trace: perf_counter is monotonic but
         # epoch-free, so every ts is relative to tracer creation.
         self._t0 = time.perf_counter()
-
-    # --- span stack (nesting / orphan accounting) ----------------------------
-
-    def _stack(self) -> list:
-        st = getattr(self._local, "stack", None)
-        if st is None:
-            st = self._local.stack = []
-        return st
-
-    def _push(self, name: str):
-        self._stack().append(name)
-
-    def _pop(self, name: str):
-        st = self._stack()
-        while st:
-            top = st.pop()
-            if top == name:
-                return
-            # a span begun inside us was never ended: count the leak
-            with self._lock:
-                self._orphans += 1
-
-    def orphans(self) -> int:
-        """Spans begun but never ended (so far) — ``begin`` without
-        ``end`` plus mispaired nesting detected at pop time."""
-        with self._lock:
-            n = self._orphans
-        st = getattr(self._local, "stack", None)
-        return n + (len(st) if st else 0)
 
     # --- recording -----------------------------------------------------------
 
     def _us(self, t: float) -> float:
         return (t - self._t0) * 1e6
-
-    def span(self, name: str, tid: int = TID_COMPUTE, cat: str = "",
-             args: dict | None = None):
-        """Context manager timing its body into one complete event."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, tid, cat, args)
-
-    def begin(self, name: str):
-        """Explicit begin/end pair (for spans that cross yield points,
-        e.g. the streamed group loop). Returns the begin timestamp."""
-        if not self.enabled:
-            return 0.0
-        self._push(name)
-        return time.perf_counter()
-
-    def end(self, name: str, t0: float, tid: int = TID_COMPUTE,
-            cat: str = "", args: dict | None = None):
-        if not self.enabled:
-            return
-        self._pop(name)
-        self.complete(name, t0, time.perf_counter() - t0, tid=tid,
-                      cat=cat, args=args)
 
     def complete(self, name: str, t0: float, dur_s: float,
                  tid: int = TID_COMPUTE, cat: str = "",
@@ -169,17 +80,6 @@ class Tracer:
               "ts": self._us(t0), "dur": max(dur_s, 0.0) * 1e6}
         if cat:
             ev["cat"] = cat
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self._events.append(ev)
-
-    def instant(self, name: str, tid: int = TID_COMPUTE,
-                args: dict | None = None):
-        if not self.enabled:
-            return
-        ev = {"name": name, "ph": "i", "pid": 0, "tid": int(tid),
-              "ts": self._us(time.perf_counter()), "s": "t"}
         if args:
             ev["args"] = args
         with self._lock:
@@ -220,7 +120,6 @@ class Tracer:
     def clear(self):
         with self._lock:
             self._events.clear()
-            self._orphans = 0
         self._t0 = time.perf_counter()
 
 

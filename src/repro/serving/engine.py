@@ -48,6 +48,7 @@ streamed mode — verifies all k in-graph, and the step emits
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import itertools
@@ -87,6 +88,8 @@ class Request:
                                      # prefix retain and an unread stream
     cached_len: int = 0              # prompt tokens adopted from the prefix
                                      # cache at admission (pos starts here)
+    t_queued: float = 0.0            # perf_counter: entered ``waiting``
+    t_admitted: float = 0.0          # perf_counter: given a slot
 
     @property
     def prefilling(self) -> bool:
@@ -110,6 +113,7 @@ def _proj(x, w_dram, w_flash, bitmap):
     return sched.split_projection(x, w_dram, flash_out, bitmap).astype(jnp.bfloat16)
 
 
+@jax.named_scope("qkv")
 def _qkv(cfg, lp, fl, x, positions, bitmap):
     """Shared QKV block (norm -> bitmap-dispatched projections -> qk-norm ->
     rope). Only wq is bitmap-dispatched (Alg. 2 rebalances the query path;
@@ -147,11 +151,14 @@ def _chunk_layer(cfg, exec_mode, bitmap, lengths, positions, block_tables,
     lp, fl, kc, vc = layer
     ap = lp["attn"]
     b, t, _ = x.shape                                    # t == chunk_tokens
-    q, k, v = _qkv(cfg, lp, fl, x, positions, bitmap)
-    attn = cm.chunk_attention_paged(
-        q, kc, vc, block_tables, lengths, k, v,
-        window=cfg.local_window, mode=exec_mode)
-    out = _proj(attn.reshape(b, t, -1), ap["wo"], fl["wo"], bitmap)
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(cfg, lp, fl, x, positions, bitmap)
+        with jax.named_scope("core"):
+            attn = cm.chunk_attention_paged(
+                q, kc, vc, block_tables, lengths, k, v,
+                window=cfg.local_window, mode=exec_mode)
+        with jax.named_scope("out"):
+            out = _proj(attn.reshape(b, t, -1), ap["wo"], fl["wo"], bitmap)
     x = x + out
     x = x + dense._ffn_apply(cfg, lp["ffn"], dense._norm(cfg, x, lp, "ln2"),
                              axis_name=axis_name)
@@ -168,11 +175,16 @@ def _moe_attn_router_body(cfg, exec_mode, lengths, positions, block_tables,
     Returns the post-attention residual, the normed FFN input, the
     router's (gates, idx), and the layer's fresh K/V."""
     b, t, _ = x.shape
-    q, k, v = _qkv(cfg, lp, None, x, positions, None)
-    attn = cm.chunk_attention_paged(
-        q, kc, vc, block_tables, lengths, k, v,
-        window=cfg.local_window, mode=exec_mode)
-    x = x + _proj(attn.reshape(b, t, -1), lp["attn"]["wo"], None, None)
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(cfg, lp, None, x, positions, None)
+        with jax.named_scope("core"):
+            attn = cm.chunk_attention_paged(
+                q, kc, vc, block_tables, lengths, k, v,
+                window=cfg.local_window, mode=exec_mode)
+        with jax.named_scope("out"):
+            out = _proj(attn.reshape(b, t, -1), lp["attn"]["wo"], None,
+                        None)
+    x = x + out
     h = dense._norm(cfg, x, lp, "ln2")
     gates, idx = moe_mod.serve_route(
         lp["moe"]["router"], h, cfg.top_k,
@@ -214,7 +226,8 @@ def _moe_expert_impl(x, h, gates, idx, slab, slab_map):
     the device SLAB holding only the routed (resident/fetched) experts.
     Same math as the resident bank — per-expert computation is independent
     of bank composition, so slab-vs-full-bank parity is exact."""
-    return x + moe_mod.serve_expert_ffn(slab, h, gates, idx, slab_map)
+    with jax.named_scope("ffn"):
+        return x + moe_mod.serve_expert_ffn(slab, h, gates, idx, slab_map)
 
 
 def _moe_expert_paged_impl(kn, x, h, gates, idx, slab, slab_map, pool_buf,
@@ -226,8 +239,9 @@ def _moe_expert_paged_impl(kn, x, h, gates, idx, slab, slab_map, pool_buf,
     static per-param (K, N) — shard-LOCAL under tensor parallelism, where
     ``axis_name`` closes each expert's contraction with one psum."""
     bank = {name: _paged(pool_buf, t, kn[name]) for name, t in slab.items()}
-    return x + moe_mod.serve_expert_ffn(bank, h, gates, idx, slab_map,
-                                        axis_name=axis_name)
+    with jax.named_scope("ffn"):
+        return x + moe_mod.serve_expert_ffn(bank, h, gates, idx, slab_map,
+                                            axis_name=axis_name)
 
 
 def _moe_fused_impl(cfg, exec_mode, kn, layers_dram, k_pool, v_pool, x, h,
@@ -307,6 +321,7 @@ def _moe_tail_impl(cfg, sched_cfg, sample_cfg, kv_aware, spec_k, kn,
                         is_decode=is_decode)
 
 
+@jax.named_scope("embed")
 def _embed_chunk(cfg, params, lengths, tokens, q_lens):
     """Token embedding + lane bookkeeping — the head of the serving step,
     shared by the monolithic and streamed data planes.
@@ -352,60 +367,68 @@ def _finish_step(cfg, sched_cfg, sample_cfg, kv_aware, spec_k, final_norm,
     of the vanilla ``(tokens (slots,), state, stats)``.
     """
     lengths = state["lengths"]
-    if cfg.norm_type == "rms":
-        x = cm.rms_norm(x, final_norm)
-    else:
-        x = cm.layer_norm(x, final_norm["g"], final_norm["b"])
-    # lm_head ONLY at each slot's last valid lane — mid-prompt positions
-    # never sample, so the (T-1) other vocab projections are skipped.
-    x_last = last_valid_hidden(x, q_lens)
-    logits = flash_matmul(x_last, lm_head, out_dtype=jnp.float32)
-    if spec_k is None:
-        toks = sample(logits, key, sample_cfg)
-        n_emit = None
-        adv = q_lens
-    else:
-        # verify lanes: lm_head over the k+1 spec lanes (a decoding slot's
-        # last valid lane is always among them), accept/reject in-graph.
-        lane_logits = flash_matmul(x[:, :spec_k + 1], lm_head,
-                                   out_dtype=jnp.float32)
-        k_verify, k_last = jax.random.split(key)
-        toks_v, n_accept = spec_mod.verify_lanes(
-            lane_logits, drafts, n_draft, k_verify, sample_cfg)
-        tok_last = sample(logits, k_last, sample_cfg)    # prefill completions
-        toks = jnp.where(is_decode[:, None], toks_v, tok_last[:, None])
-        n_emit = jnp.where(is_decode, n_accept + 1, 1).astype(jnp.int32)
-        adv = jnp.where(is_decode, n_emit, q_lens)       # length REWIND
+    with jax.named_scope("lm_head"):
+        if cfg.norm_type == "rms":
+            x = cm.rms_norm(x, final_norm)
+        else:
+            x = cm.layer_norm(x, final_norm["g"], final_norm["b"])
+        # lm_head ONLY at each slot's last valid lane — mid-prompt
+        # positions never sample, so the (T-1) other vocab projections are
+        # skipped.
+        x_last = last_valid_hidden(x, q_lens)
+        logits = flash_matmul(x_last, lm_head, out_dtype=jnp.float32)
+        if spec_k is not None:
+            # verify lanes: lm_head over the k+1 spec lanes (a decoding
+            # slot's last valid lane is always among them).
+            lane_logits = flash_matmul(x[:, :spec_k + 1], lm_head,
+                                       out_dtype=jnp.float32)
+    with jax.named_scope("sample"):
+        if spec_k is None:
+            toks = sample(logits, key, sample_cfg)
+            n_emit = None
+            adv = q_lens
+        else:
+            # accept/reject the verify lanes in-graph
+            k_verify, k_last = jax.random.split(key)
+            toks_v, n_accept = spec_mod.verify_lanes(
+                lane_logits, drafts, n_draft, k_verify, sample_cfg)
+            tok_last = sample(logits, k_last, sample_cfg)  # prefill ends
+            toks = jnp.where(is_decode[:, None], toks_v, tok_last[:, None])
+            n_emit = jnp.where(is_decode, n_accept + 1, 1).astype(jnp.int32)
+            adv = jnp.where(is_decode, n_emit, q_lens)     # length REWIND
 
     # --- paged KV scatter: ONE batched write for all layers/slots/lanes ------
-    block_size = state["k"].shape[2]
-    max_blocks = block_tables.shape[1]
-    lane = jnp.arange(positions.shape[1])[None, :]
-    pos = positions                                      # (slots, T)
-    valid = lane < q_lens[:, None]
-    blk_idx = jnp.clip(pos // block_size, 0, max_blocks - 1)
-    blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
-    # invalid lanes (and any unmapped table hit) land in the dump block 0
-    blk = jnp.where(valid, blk, 0)
-    off = jnp.where(valid, pos % block_size, 0)
-    kd = state["k"].at[:, blk, off].set(k_new.astype(state["k"].dtype))
-    vd = state["v"].at[:, blk, off].set(v_new.astype(state["v"].dtype))
-    new_lengths = lengths + adv
+    with jax.named_scope("kv_write"):
+        block_size = state["k"].shape[2]
+        max_blocks = block_tables.shape[1]
+        lane = jnp.arange(positions.shape[1])[None, :]
+        pos = positions                                  # (slots, T)
+        valid = lane < q_lens[:, None]
+        blk_idx = jnp.clip(pos // block_size, 0, max_blocks - 1)
+        blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
+        # invalid lanes (and any unmapped table hit) land in the dump block 0
+        blk = jnp.where(valid, blk, 0)
+        off = jnp.where(valid, pos % block_size, 0)
+        kd = state["k"].at[:, blk, off].set(k_new.astype(state["k"].dtype))
+        vd = state["v"].at[:, blk, off].set(v_new.astype(state["v"].dtype))
+        new_lengths = lengths + adv
 
     # --- Algorithm 2: KV-cache-aware rebalance, in-graph -------------------
     # admitted (not worked): a budget-starved prefill slot's cached KV
     # still sets the attention-latency picture Algorithm 2 reacts to.
     # Speculative lengths count ACCEPTED rows only (the rewound length is
     # the attention context every later step actually reads).
-    kv_len = jnp.max(jnp.where(admitted, new_lengths, 0))
-    new_bitmap, new_prev, delta = sched.kv_aware_step(
-        state["bitmap"], state["prev_cycles"], kv_len,
-        cfg.d_model, cfg.n_kv_heads, cfg.head_dim, sched_cfg, kv_aware)
+    with jax.named_scope("alg2"):
+        kv_len = jnp.max(jnp.where(admitted, new_lengths, 0))
+        new_bitmap, new_prev, delta = sched.kv_aware_step(
+            state["bitmap"], state["prev_cycles"], kv_len,
+            cfg.d_model, cfg.n_kv_heads, cfg.head_dim, sched_cfg, kv_aware)
+        npu_frac = sched.npu_fraction(new_bitmap)
 
     new_state = {"k": kd, "v": vd, "lengths": new_lengths,
                  "bitmap": new_bitmap, "prev_cycles": new_prev}
     stats = {"kv_len": kv_len, "delta_cycles": delta,
-             "npu_fraction": sched.npu_fraction(new_bitmap)}
+             "npu_fraction": npu_frac}
     if spec_k is None:
         return toks, new_state, stats
     dec = is_decode
@@ -418,6 +441,7 @@ def _finish_step(cfg, sched_cfg, sample_cfg, kv_aware, spec_k, final_norm,
     return toks, n_emit, new_state, stats
 
 
+@jax.named_scope("embed")
 def _embed_spec(cfg, proposer, spec_k, params, lengths, tokens, q_lens,
                 hist, hist_lens, draft_cap):
     """Speculative head of the serving step: IN-GRAPH drafting + embedding.
@@ -482,16 +506,17 @@ def _step_impl(cfg, sched_cfg, sample_cfg, kv_aware, exec_mode, unroll,
         body = functools.partial(_chunk_layer, cfg, exec_mode, bitmap,
                                  ctx_lens, positions, block_tables)
         xs = (params["layers"], attn_flash, state["k"], state["v"])
-    if unroll:
-        # eager reference: interpreted Python loop over layers (seed-style)
-        ks, vs = [], []
-        for li in range(cfg.n_layers):
-            x, (kl, vl) = body(x, jax.tree.map(lambda a: a[li], xs))
-            ks.append(kl)
-            vs.append(vl)
-        k_new, v_new = jnp.stack(ks), jnp.stack(vs)   # (L, slots, T, KV, Dh)
-    else:
-        x, (k_new, v_new) = jax.lax.scan(body, x, xs)
+    with jax.named_scope("layers"):
+        if unroll:
+            # eager reference: interpreted Python loop over layers
+            ks, vs = [], []
+            for li in range(cfg.n_layers):
+                x, (kl, vl) = body(x, jax.tree.map(lambda a: a[li], xs))
+                ks.append(kl)
+                vs.append(vl)
+            k_new, v_new = jnp.stack(ks), jnp.stack(vs)  # (L, S, T, KV, Dh)
+        else:
+            x, (k_new, v_new) = jax.lax.scan(body, x, xs)
 
     return _finish_step(cfg, sched_cfg, sample_cfg, kv_aware, spec_k,
                         params["final_norm"], params["lm_head"], state, x,
@@ -547,8 +572,9 @@ def _stream_group_impl(cfg, exec_mode, kv_aware, group_size, shapes,
                             block_tables, x, (lp, fl_attn, kcl, vcl),
                             axis_name=axis_name)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (lp_g, window["ffn"], window["attn"], kc, vc))
+    with jax.named_scope("layers"):
+        x, (k_new, v_new) = jax.lax.scan(
+            body, x, (lp_g, window["ffn"], window["attn"], kc, vc))
     return x, k_new, v_new
 
 
@@ -725,6 +751,24 @@ class Engine:
         self._c_step_tokens = self.obs.counter(
             "engine_tokens_total", "tokens processed by the step loop",
             label_names=("kind",))
+        # request lifecycle (split of time to first token) and the
+        # scheduler/KV state each step plans under
+        self._h_admit_wait = self.obs.histogram(
+            "engine_admission_wait_seconds",
+            "request enters the waiting queue -> admitted to a slot")
+        self._h_prefill = self.obs.histogram(
+            "engine_prefill_seconds",
+            "admission -> the step whose sync hands over the first token")
+        self._h_budget = self.obs.histogram(
+            "engine_step_token_budget",
+            "per-step token budget from scheduler.step_token_budget",
+            buckets=obs.log_buckets(1.0, 1024.0, 4))
+        self._c_kv_reserved = self.obs.counter(
+            "engine_kv_rows_reserved_total",
+            "per step, KV rows the active requests reserved at admission")
+        self._c_kv_used = self.obs.counter(
+            "engine_kv_rows_used_total",
+            "per step, KV rows the active requests hold")
         self._phases: dict[str, float] = {}
         # per-slot token histories feeding the in-graph drafter (spec mode)
         if spec_cfg is not None:
@@ -746,9 +790,10 @@ class Engine:
         elif self.streamed:
             self._build_stream_fns(exec_mode)
         elif compiled:
-            def counted(*args):
+            def serve_step(*args):
                 # Python body only runs while jax traces; compiled replays
-                # skip it — so this counts traces, not steps.
+                # skip it — so this counts traces, not steps. The name is
+                # the compiled module's in a profile (``jit_serve_step``).
                 self._trace_count += 1
                 return step(*args)
 
@@ -756,7 +801,7 @@ class Engine:
             # update of device-resident serving state. (CPU ignores donation
             # and warns, so only donate where it lands.)
             donate = (2,) if jax.default_backend() != "cpu" else ()
-            self._step_fn = jax.jit(counted, donate_argnums=donate)
+            self._step_fn = jax.jit(serve_step, donate_argnums=donate)
         else:
             self._step_fn = step
 
@@ -1420,49 +1465,48 @@ class Engine:
         layer pass is shared by ALL of a slot's verify lanes: one window
         rotation per step amortizes over every accepted token."""
         del params, attn_flash                       # store-resident tier
-        t = time.perf_counter()
-        if self.spec_cfg is None:
-            drafts = n_draft = None
-            x, positions, ctx_lens = self._embed_fn(
-                self._dram_params, state["lengths"], tokens, q_lens)
-        else:
-            x, positions, ctx_lens, q_lens, drafts, n_draft = self._embed_fn(
-                self._dram_params, state["lengths"], tokens, q_lens, hist,
-                hist_lens, draft_cap)
-        t = self._phase("embed", t)
+        with self._phase("embed"):
+            if self.spec_cfg is None:
+                drafts = n_draft = None
+                x, positions, ctx_lens = self._embed_fn(
+                    self._dram_params, state["lengths"], tokens, q_lens)
+            else:
+                x, positions, ctx_lens, q_lens, drafts, n_draft = \
+                    self._embed_fn(self._dram_params, state["lengths"],
+                                   tokens, q_lens, hist, hist_lens,
+                                   draft_cap)
         ks, vs = [], []
         # manual iteration so the window-queue wait (the stream-wait
         # stall) times separately from the group's compute dispatch
         it = self.streamer.stream()
         while True:
-            try:
-                g, window = next(it)
-            except StopIteration:
+            with self._phase("stream_wait"):
+                item = next(it, None)
+            if item is None:
                 break
-            t = self._phase("stream_wait", t)
-            lo = jnp.int32(g * self.stream_cfg.group_size)
-            # dispatch under the pool lock: the window's liveness ref
-            # guarantees its slots are mapped, and the lock keeps the
-            # worker's donating (in-place) uploads from deleting the
-            # buffer handle mid-dispatch.
-            win = {"ffn": window["ffn"], "attn": window["attn"]}
-            x, k_g, v_g = self.wpool.dispatch(lambda buf: self._group_fn(
-                self._layers_dram, win, buf, state["k"],
-                state["v"], x, positions, ctx_lens, block_tables,
-                state["bitmap"], lo))
+            g, window = item
+            with self._phase("group_dispatch"):
+                lo = jnp.int32(g * self.stream_cfg.group_size)
+                # dispatch under the pool lock: the window's liveness ref
+                # guarantees its slots are mapped, and the lock keeps the
+                # worker's donating (in-place) uploads from deleting the
+                # buffer handle mid-dispatch.
+                win = {"ffn": window["ffn"], "attn": window["attn"]}
+                x, k_g, v_g = self.wpool.dispatch(lambda buf: self._group_fn(
+                    self._layers_dram, win, buf, state["k"],
+                    state["v"], x, positions, ctx_lens, block_tables,
+                    state["bitmap"], lo))
             ks.append(k_g)
             vs.append(v_g)
-            t = self._phase("group_dispatch", t)
-        k_new = jnp.concatenate(ks, axis=0)          # (L, slots, T, KV, Dh)
-        v_new = jnp.concatenate(vs, axis=0)
-        args = (self._dram_params["final_norm"], self._lm_head, state, x,
-                k_new, v_new, q_lens, admitted, positions, block_tables,
-                key)
-        if self.spec_cfg is not None:
-            args += (drafts, n_draft, is_decode)
-        out = self._finish_fn(*args)
-        self._phase("finish", t)
-        return out
+        with self._phase("finish"):
+            k_new = jnp.concatenate(ks, axis=0)      # (L, slots, T, KV, Dh)
+            v_new = jnp.concatenate(vs, axis=0)
+            args = (self._dram_params["final_norm"], self._lm_head, state,
+                    x, k_new, v_new, q_lens, admitted, positions,
+                    block_tables, key)
+            if self.spec_cfg is not None:
+                args += (drafts, n_draft, is_decode)
+            return self._finish_fn(*args)
 
     def _build_stream_fns_moe(self, exec_mode):
         """The expert-paged MoE data plane: THREE jitted pieces (HEAD
@@ -1556,42 +1600,43 @@ class Engine:
         next step)."""
         del params, attn_flash                       # store-resident tier
         cfg, cache = self.cfg, self.expert_cache
-        t = time.perf_counter()
         head_args = (self._layers_dram, state["k"], state["v"],
                      self._dram_params, state["lengths"], tokens, q_lens,
                      block_tables)
-        if self.spec_cfg is None:
-            drafts = n_draft = None
-            x, h, gates, idx, k_l, v_l, positions, ctx_lens = \
-                self._head_fn(*head_args)
-            lane_bound = self._host_q_lens
-        else:
-            (x, h, gates, idx, k_l, v_l, positions, ctx_lens, q_lens,
-             drafts, n_draft) = self._head_fn(*head_args, hist, hist_lens,
-                                              draft_cap)
-            # verify lanes grow q_lens IN-GRAPH (by n_draft <= draft_cap);
-            # the host-side routed-expert filter uses the superset bound so
-            # a draft lane's routing is never dropped from the slab.
-            lane_bound = self._host_q_lens + self._host_draft_cap
-        # whole-step prefetch lead: the per-layer request below gives the
-        # worker only one layer's compute (~ms) to land its fetches — on
-        # fast layers the compute path wins the race and every miss is a
-        # synchronous stall. The per-slot router histories already know
-        # each layer's likely experts, so queue EVERY layer's predictions
-        # up front (one batched transfer in the worker) and let the layer
-        # loop's requests merely top up with the freshest signal.
-        active = [s for s in range(len(lane_bound)) if lane_bound[s] > 0]
-        if self._steps_done > 0:
-            for li in range(cfg.n_layers):
-                self._request_prefetch(li, self._e_slab, slots=active)
-        t = self._phase("head_dispatch", t)
+        with self._phase("head_dispatch"):
+            if self.spec_cfg is None:
+                drafts = n_draft = None
+                x, h, gates, idx, k_l, v_l, positions, ctx_lens = \
+                    self._head_fn(*head_args)
+                lane_bound = self._host_q_lens
+            else:
+                (x, h, gates, idx, k_l, v_l, positions, ctx_lens, q_lens,
+                 drafts, n_draft) = self._head_fn(*head_args, hist,
+                                                  hist_lens, draft_cap)
+                # verify lanes grow q_lens IN-GRAPH (by n_draft <=
+                # draft_cap); the host-side routed-expert filter uses the
+                # superset bound so a draft lane's routing is never
+                # dropped from the slab.
+                lane_bound = self._host_q_lens + self._host_draft_cap
+            # whole-step prefetch lead: the per-layer request below gives
+            # the worker only one layer's compute (~ms) to land its
+            # fetches — on fast layers the compute path wins the race and
+            # every miss is a synchronous stall. The per-slot router
+            # histories already know each layer's likely experts, so queue
+            # EVERY layer's predictions up front (one batched transfer in
+            # the worker) and let the layer loop's requests merely top up
+            # with the freshest signal.
+            active = [s for s in range(len(lane_bound)) if lane_bound[s] > 0]
+            if self._steps_done > 0:
+                for li in range(cfg.n_layers):
+                    self._request_prefetch(li, self._e_slab, slots=active)
         # layer 0's attention+router already ran inside the head trace
         # (no pool operand — embed/attn weights are DRAM-resident).
         ks, vs = [k_l], [v_l]
         out = None
         for li in range(cfg.n_layers):
-            idx_host = np.asarray(idx)               # layer li's routing
-            t = self._phase("route_sync", t)
+            with self._phase("route_sync"):
+                idx_host = np.asarray(idx)           # layer li's routing
             by_slot = sched.routed_experts_by_slot(idx_host, lane_bound)
             routed = sched.routed_experts(idx_host, lane_bound)
             cache.observe(li, routed)
@@ -1600,10 +1645,9 @@ class Engine:
             self._max_routed_seen = max(self._max_routed_seen, len(routed))
             self._request_prefetch((li + 1) % cfg.n_layers, len(routed),
                                    slots=by_slot.keys())
-            t = time.perf_counter()
-            slab, slab_map, held, transients, missing = \
-                self._acquire_experts(li, routed)
-            t = self._phase("expert_acquire", t)
+            with self._phase("expert_acquire"):
+                slab, slab_map, held, transients, missing = \
+                    self._acquire_experts(li, routed)
             for s, ids in by_slot.items():
                 cache.note_slot_route(s, len(ids),
                                       sum(1 for e in ids
@@ -1613,14 +1657,14 @@ class Engine:
             # snapshot-and-dispatch must be atomic against them.
             if li + 1 < cfg.n_layers:
                 # layer li's experts fused with layer li+1's attn+router
-                x, h, gates, idx, k_l, v_l = self.wpool.dispatch(
-                    lambda buf: self._fused_fn(
-                        self._layers_dram, state["k"], state["v"], x, h,
-                        gates, idx, slab, slab_map, buf, positions,
-                        ctx_lens, block_tables, jnp.int32(li + 1)))
+                with self._phase("fused_dispatch"):
+                    x, h, gates, idx, k_l, v_l = self.wpool.dispatch(
+                        lambda buf: self._fused_fn(
+                            self._layers_dram, state["k"], state["v"], x,
+                            h, gates, idx, slab, slab_map, buf, positions,
+                            ctx_lens, block_tables, jnp.int32(li + 1)))
                 ks.append(k_l)
                 vs.append(v_l)
-                t = self._phase("fused_dispatch", t)
             else:        # last layer: experts fused with the finish step
                 k_new = jnp.stack(ks, axis=0)    # (L, slots, T, KV, Dh)
                 v_new = jnp.stack(vs, axis=0)
@@ -1630,9 +1674,9 @@ class Engine:
                         block_tables, key)
                 if self.spec_cfg is not None:
                     post += (drafts, n_draft, is_decode)
-                out = self.wpool.dispatch(
-                    lambda buf: self._tail_fn(*pre, buf, *post))
-                t = self._phase("tail_dispatch", t)
+                with self._phase("tail_dispatch"):
+                    out = self.wpool.dispatch(
+                        lambda buf: self._tail_fn(*pre, buf, *post))
             # dispatch has captured the pool buffer: NOW the held
             # entries can release and the rejected transients can free.
             for hk in held:
@@ -1724,19 +1768,22 @@ class Engine:
         unused = self._e_slab - max(self._max_routed_seen, 1)
         cache.resize(cache.capacity + unused * self._max_expert_bytes)
 
-    def _phase(self, name: str, t0: float, now: float | None = None) -> float:
-        """Accumulate one step-phase interval (ObsPlane): seconds since
-        ``t0`` land in this step's phase breakdown and — when tracing is
-        armed — as a span on the compute track. Returns now, so phase
-        boundaries chain: ``t = self._phase("embed", t)``."""
-        if now is None:
-            now = time.perf_counter()
-        self._phases[name] = self._phases.get(name, 0.0) + (now - t0)
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One step phase (ObsPlane), timed around its body, on every
+        plane: a ``jax.profiler.TraceAnnotation`` puts it in the profiler's
+        trace on the device trace's clock (a no-op outside a profiler
+        session), its seconds add to this step's breakdown (the
+        ``engine_step_seconds`` histogram, the timeline), and an armed
+        Tracer records it on the compute track."""
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
+            yield
+            dt = time.perf_counter() - t0
+        self._phases[name] = self._phases.get(name, 0.0) + dt
         tracer = obs.default_tracer()
         if tracer.enabled:
-            tracer.complete(name, t0, now - t0, tid=obs.TID_COMPUTE,
-                            cat="step")
-        return now
+            tracer.complete(name, t0, dt, tid=obs.TID_COMPUTE, cat="step")
 
     def _stream_stall_s(self) -> float:
         """Seconds the compute path has spent blocked on the weight stream:
@@ -1890,6 +1937,7 @@ class Engine:
                 raise ValueError(
                     f"request needs {req.kv_rows} KV rows > max_seq={cap}")
             self.requests[rid] = req
+            req.t_queued = time.perf_counter()
             self.waiting.append(req)
             self._admit()
             return rid
@@ -1962,6 +2010,9 @@ class Engine:
             if slot is None:
                 break
             req.slot = slot
+            req.t_admitted = time.perf_counter()
+            self._lifecycle(self._h_admit_wait, "queue", req, req.t_queued,
+                            req.t_admitted)
             if shared:
                 req.cached_len = len(shared) * self.pool.block_size
                 req.pos = req.cached_len
@@ -1972,6 +2023,18 @@ class Engine:
                 self._accept_ema[slot] = 1.0
             self.waiting.popleft()
             self._cv.notify_all()
+
+    def _lifecycle(self, hist, name: str, req: Request, t0: float,
+                   t1: float):
+        """One wait in a request's life (ObsPlane): its seconds into
+        ``hist`` and, with tracing armed, a span on the request's track
+        (``t0``/``t1`` are ``perf_counter`` readings)."""
+        hist.observe(t1 - t0)
+        tracer = obs.default_tracer()
+        if tracer.enabled:
+            tracer.complete(name, t0, t1 - t0,
+                            tid=tracer.request_tid(req.rid), cat="request",
+                            args={"rid": req.rid})
 
     def _sweep_cancelled(self):
         """Reclaim cancelled requests' resources (under the lock, at the
@@ -2090,142 +2153,167 @@ class Engine:
         Thread-safe — one step at a time, producers interleave between
         steps; cancelled requests are swept FIRST, so a disconnect's KV
         blocks are back on the free list within one call."""
-        with self._cv:
-            self._sweep_cancelled()
-            n = self._step_locked()
-            self._cv.notify_all()
-            return n
+        # the profiler's step marker; not a Tracer span, so an idle gap is
+        # labelled by the phase inside the step that overlaps it
+        with jax.profiler.StepTraceAnnotation("serve_step",
+                                              step_num=self._steps_done):
+            with self._cv:
+                self._sweep_cancelled()
+                n = self._step_locked()
+                self._cv.notify_all()
+                return n
 
     def _step_locked(self) -> int:
         t_plan0 = time.perf_counter()
         self._phases = {}                # this step's ObsPlane breakdown
-        self._admit()
-        spec = self.spec_cfg is not None
-        decode_slots, prefill_slots = [], []
-        # ARRIVAL order (rid), not slot order: recycled slot ids would
-        # otherwise let a later prompt monopolize the prefill budget ahead
-        # of an earlier one (plan_chunks funds prefill FCFS as given).
-        for slot, rid in sorted(self.pool.active.items(), key=lambda kv: kv[1]):
-            req = self.requests[rid]
-            if req.done:
-                continue
-            if req.prefilling:
-                prefill_slots.append((slot, len(req.prompt) - req.pos))
-            elif spec:
-                decode_slots.append((slot, 1 + self._draft_cap(req)))
-            else:
-                decode_slots.append(slot)
-        budget = sched.step_token_budget(self.admission_cfg, self._npu_frac,
-                                         self._stall_frac)
-        # snapshot AFTER list-building: a lock-free cancel() landing since
-        # the req.done filter above must not be granted lanes or budget.
-        cancelled = {slot for slot, rid in self.pool.active.items()
-                     if self.requests[rid].done}
-        plan = sched.plan_chunks(decode_slots, prefill_slots, budget,
-                                 self.admission_cfg.chunk_tokens,
-                                 cancelled=cancelled)
-        if not plan:
-            return 0
-        n, t_chunk = self.pool.n_slots, self.admission_cfg.chunk_tokens
-        tokens = np.zeros((n, t_chunk), np.int32)
-        q_lens = np.zeros((n,), np.int32)
-        admitted = np.zeros((n,), bool)
-        if spec:
-            draft_cap = np.zeros((n,), np.int32)
-            is_decode = np.zeros((n,), bool)
-        for slot, _ in prefill_slots:
-            admitted[slot] = True
-        admitted[[s if isinstance(s, int) else s[0]
-                  for s in decode_slots]] = True
-        for slot, cnt in plan.items():
-            req = self.requests[self.pool.active[slot]]
-            if req.prefilling:
-                chunk = req.prompt[req.pos:req.pos + cnt]
-                tokens[slot, :len(chunk)] = chunk
-                q_lens[slot] = len(chunk)
-            else:
-                tokens[slot, 0] = req.out[-1]
-                q_lens[slot] = 1          # + n_draft lanes added in-graph
-                if spec:
-                    is_decode[slot] = True
-                    draft_cap[slot] = cnt - 1   # budget-clamped verify lanes
-                    seq = req.prompt + req.out
-                    hl = min(len(seq), self._hist.shape[1])
-                    self._hist[slot, :hl] = seq[-hl:]
-                    self._hist_lens[slot] = hl
-            # map physical blocks for this step's writes — ALL lanes, draft
-            # lanes included (host control plane; draws on the admission
-            # reservation, so it cannot fail)
-            self.pool.ensure(slot, int(self.pool.lengths[slot]) + cnt)
-        self._key, sk = jax.random.split(self._key)
-        if self.streamed_moe:
-            # host-side lane bounds for the routed-expert filter (spec
-            # verify lanes are added in-graph; the filter uses the
-            # superset bound q_lens + draft_cap)
-            self._host_q_lens = q_lens.copy()
-            self._host_draft_cap = draft_cap.copy() if spec else None
-        state = dict(self.pool.device_state(),
-                     bitmap=self.bitmap, prev_cycles=self._prev_cycles)
-        t_step0 = self._phase("plan", t_plan0)
+        with self._phase("plan"):
+            self._admit()
+            spec = self.spec_cfg is not None
+            decode_slots, prefill_slots = [], []
+            # ARRIVAL order (rid), not slot order: recycled slot ids would
+            # otherwise let a later prompt monopolize the prefill budget
+            # ahead of an earlier one (plan_chunks funds prefill FCFS as
+            # given).
+            for slot, rid in sorted(self.pool.active.items(),
+                                    key=lambda kv: kv[1]):
+                req = self.requests[rid]
+                if req.done:
+                    continue
+                if req.prefilling:
+                    prefill_slots.append((slot, len(req.prompt) - req.pos))
+                elif spec:
+                    decode_slots.append((slot, 1 + self._draft_cap(req)))
+                else:
+                    decode_slots.append(slot)
+            budget = sched.step_token_budget(self.admission_cfg,
+                                             self._npu_frac,
+                                             self._stall_frac)
+            # snapshot AFTER list-building: a lock-free cancel() landing
+            # since the req.done filter above must not be granted lanes or
+            # budget.
+            cancelled = {slot for slot, rid in self.pool.active.items()
+                         if self.requests[rid].done}
+            plan = sched.plan_chunks(decode_slots, prefill_slots, budget,
+                                     self.admission_cfg.chunk_tokens,
+                                     cancelled=cancelled)
+            if not plan:
+                return 0
+            self._h_budget.observe(budget)
+            reserved = used = 0
+            for slot, rid in self.pool.active.items():
+                reserved += self.requests[rid].kv_rows
+                used += int(self.pool.lengths[slot])
+            self._c_kv_reserved.inc(reserved)
+            self._c_kv_used.inc(used)
+            n, t_chunk = self.pool.n_slots, self.admission_cfg.chunk_tokens
+            tokens = np.zeros((n, t_chunk), np.int32)
+            q_lens = np.zeros((n,), np.int32)
+            admitted = np.zeros((n,), bool)
+            if spec:
+                draft_cap = np.zeros((n,), np.int32)
+                is_decode = np.zeros((n,), bool)
+            for slot, _ in prefill_slots:
+                admitted[slot] = True
+            admitted[[s if isinstance(s, int) else s[0]
+                      for s in decode_slots]] = True
+            for slot, cnt in plan.items():
+                req = self.requests[self.pool.active[slot]]
+                if req.prefilling:
+                    chunk = req.prompt[req.pos:req.pos + cnt]
+                    tokens[slot, :len(chunk)] = chunk
+                    q_lens[slot] = len(chunk)
+                else:
+                    tokens[slot, 0] = req.out[-1]
+                    q_lens[slot] = 1      # + n_draft lanes added in-graph
+                    if spec:
+                        is_decode[slot] = True
+                        # budget-clamped verify lanes
+                        draft_cap[slot] = cnt - 1
+                        seq = req.prompt + req.out
+                        hl = min(len(seq), self._hist.shape[1])
+                        self._hist[slot, :hl] = seq[-hl:]
+                        self._hist_lens[slot] = hl
+                # map physical blocks for this step's writes — ALL lanes,
+                # draft lanes included (host control plane; draws on the
+                # admission reservation, so it cannot fail)
+                self.pool.ensure(slot, int(self.pool.lengths[slot]) + cnt)
+            self._key, sk = jax.random.split(self._key)
+            if self.streamed_moe:
+                # host-side lane bounds for the routed-expert filter (spec
+                # verify lanes are added in-graph; the filter uses the
+                # superset bound q_lens + draft_cap)
+                self._host_q_lens = q_lens.copy()
+                self._host_draft_cap = draft_cap.copy() if spec else None
+            state = dict(self.pool.device_state(),
+                         bitmap=self.bitmap, prev_cycles=self._prev_cycles)
+        t_step0 = time.perf_counter()
         stall0 = self._stream_stall_s()
-        args = (self.params, self.attn_flash, state,
-                jnp.asarray(tokens), jnp.asarray(q_lens),
-                jnp.asarray(admitted), self.pool.block_tables_dev(), sk)
-        if spec:
-            args += (jnp.asarray(self._hist), jnp.asarray(self._hist_lens),
-                     jnp.asarray(draft_cap), jnp.asarray(is_decode))
-            toks, n_emit, state, stats = self._step_fn(*args)
-            n_emit_host = np.asarray(n_emit)
-        else:
-            toks, state, stats = self._step_fn(*args)
-        t_sync0 = time.perf_counter()
-        if not self.streamed:
-            # monolithic plane: the whole jitted call is one dispatch
-            # (streamed planes decomposed it into embed/group/finish above)
-            self._phase("dispatch", t_step0, now=t_sync0)
-        self.pool.set_device_state(state)
-        self.bitmap = state["bitmap"]
-        self._prev_cycles = state["prev_cycles"]
-        # the step's only device->host syncs: sampled tokens + stat scalars
-        toks_host = np.asarray(toks)      # (slots,) — or (slots, k+1) spec
-        n_processed = n_prefill = 0
-        for slot in plan:
-            req = self.requests[self.pool.active[slot]]
-            cnt = int(q_lens[slot])
-            if req.prefilling:
-                n_processed += cnt
-                n_prefill += cnt
-                self.pool.bump(slot, cnt)
-                req.pos += cnt
-                if not req.prefilling:
-                    # just-completed prefill sampled one token at its last
-                    # lane
-                    req.out.append(int(toks_host[slot, 0] if spec
-                                       else toks_host[slot]))
-            elif spec:
-                # verify step: n_accept + 1 tokens emitted; the pool length
-                # REWINDS to the accepted rows (host mirror here — device
-                # lengths advanced by the same amount in-graph; rejected
-                # lanes' K/V stays in place, unreachable, overwritten later)
-                ne = int(n_emit_host[slot])
-                new_len = int(self.pool.lengths[slot]) + ne
-                take = min(ne, req.max_new - len(req.out))
-                req.out.extend(int(t) for t in toks_host[slot, :take])
-                self.pool.rewind(slot, new_len)
-                n_processed += ne
+        with self._phase("h2d"):
+            args = (self.params, self.attn_flash, state,
+                    jnp.asarray(tokens), jnp.asarray(q_lens),
+                    jnp.asarray(admitted), self.pool.block_tables_dev(), sk)
+            if spec:
+                args += (jnp.asarray(self._hist),
+                         jnp.asarray(self._hist_lens),
+                         jnp.asarray(draft_cap), jnp.asarray(is_decode))
+        # monolithic plane: the whole jitted call is one dispatch (streamed
+        # planes time their embed/group/finish pieces themselves)
+        with (contextlib.nullcontext() if self.streamed
+              else self._phase("dispatch")):
+            out = self._step_fn(*args)
+        with self._phase("sync"):
+            if spec:
+                toks, n_emit, state, stats = out
+                n_emit_host = np.asarray(n_emit)
             else:
-                self.pool.bump(slot, cnt)
-                req.out.append(int(toks_host[slot]))
-                n_processed += cnt
-            if req.cancelled:
-                # cancel() landed mid-step: reclaim NOW (the "within one
-                # step" guarantee); the unread output is discarded.
-                self.pool.release(slot)
-            elif not req.prefilling and len(req.out) >= req.max_new:
-                req.done = True
-                self._finish_request(req, slot)
-        st = jax.device_get(stats)
-        self._phase("sync", t_sync0)
+                toks, state, stats = out
+            self.pool.set_device_state(state)
+            self.bitmap = state["bitmap"]
+            self._prev_cycles = state["prev_cycles"]
+            # the step's only device->host syncs: sampled tokens + stat
+            # scalars
+            toks_host = np.asarray(toks)  # (slots,) — or (slots, k+1) spec
+            t_toks = time.perf_counter()
+            n_processed = n_prefill = 0
+            for slot in plan:
+                req = self.requests[self.pool.active[slot]]
+                cnt = int(q_lens[slot])
+                if req.prefilling:
+                    n_processed += cnt
+                    n_prefill += cnt
+                    self.pool.bump(slot, cnt)
+                    req.pos += cnt
+                    if not req.prefilling:
+                        # just-completed prefill sampled one token at its
+                        # last lane
+                        req.out.append(int(toks_host[slot, 0] if spec
+                                           else toks_host[slot]))
+                        self._lifecycle(self._h_prefill, "prefill", req,
+                                        req.t_admitted, t_toks)
+                elif spec:
+                    # verify step: n_accept + 1 tokens emitted; the pool
+                    # length REWINDS to the accepted rows (host mirror here —
+                    # device lengths advanced by the same amount in-graph;
+                    # rejected lanes' K/V stays in place, unreachable,
+                    # overwritten later)
+                    ne = int(n_emit_host[slot])
+                    new_len = int(self.pool.lengths[slot]) + ne
+                    take = min(ne, req.max_new - len(req.out))
+                    req.out.extend(int(t) for t in toks_host[slot, :take])
+                    self.pool.rewind(slot, new_len)
+                    n_processed += ne
+                else:
+                    self.pool.bump(slot, cnt)
+                    req.out.append(int(toks_host[slot]))
+                    n_processed += cnt
+                if req.cancelled:
+                    # cancel() landed mid-step: reclaim NOW (the "within
+                    # one step" guarantee); the unread output is discarded.
+                    self.pool.release(slot)
+                elif not req.prefilling and len(req.out) >= req.max_new:
+                    req.done = True
+                    self._finish_request(req, slot)
+            st = jax.device_get(stats)
         self._npu_frac = float(st["npu_fraction"])
         entry = {
             "kv_len": int(st["kv_len"]),

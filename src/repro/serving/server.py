@@ -185,6 +185,10 @@ class ServeFront:
             "mean inter-token interval per finished request")
         self._h_e2e = self.obs.histogram(
             "serve_e2e_seconds", "request submit -> stream finish")
+        self._h_submit_wait = self.obs.histogram(
+            "serve_submit_wait_seconds",
+            "add_request entry -> Engine.submit returned (front and engine "
+            "lock waits)")
         self._c_finish = self.obs.counter(
             "serve_finish_total", "finished request streams by outcome",
             label_names=("reason",))
@@ -224,6 +228,7 @@ class ServeFront:
         ``max_time_s`` is a per-request serving deadline: a request still
         generating past it is cancelled by the loop thread and finishes
         with ``finish_reason="timeout"`` (tokens sampled so far kept)."""
+        t_enter = time.perf_counter()    # before any lock: the submit wait
         wait_deadline = (None if timeout is None
                          else time.monotonic() + timeout)
         with self._cv:
@@ -242,11 +247,18 @@ class ServeFront:
                                    + (f" ({self.error!r})" if self.error
                                       else ""))
             rid = self.engine.submit(list(prompt), max_new=max_new)
+            t_submitted = time.perf_counter()
             h = RequestHandle(self, rid,
                               deadline=(None if max_time_s is None
                                         else time.monotonic() + max_time_s))
             self._handles[rid] = h
             self._progress[rid] = 0
+        self._h_submit_wait.observe(t_submitted - t_enter)
+        tracer = obs.default_tracer()
+        if tracer.enabled:
+            tracer.complete("submit_wait", t_enter, t_submitted - t_enter,
+                            tid=tracer.request_tid(rid), cat="request",
+                            args={"rid": rid})
         self._wake.set()
         return h
 
