@@ -14,9 +14,14 @@ storage overhead, i.e. an L(72,64) code in the paper's notation.
 For a weight matrix ``W`` of shape (K, N) stored as uint8 "raw bytes", the
 parity plane has shape (K//8, N).
 
-All functions here are pure jnp and safe to call inside a Pallas kernel body
-(no gathers, no dynamic shapes): parity is computed with shift-XOR folds and
-the single-bit correction is a broadcast compare against a constant table.
+All functions here are pure jnp (no gathers, no dynamic shapes). ``encode``
+computes parity with shift-XOR folds. ``check_and_correct`` reads each
+codeword once: every byte maps to an 8-bit contribution (7 masked-byte
+parities by ``population_count`` plus the byte's parity), the 8 contributions
+XOR-reduce to the codeword's computed parity byte, and the flipped bit's
+place comes from the syndrome by arithmetic (``clz``), so no intermediate is
+larger than the weight. Mosaic does not lower the XOR ``reduce`` (nor the
+codeword reshape), so the ECC Pallas kernels run in interpret mode only.
 
 Semantics (verified by property tests in tests/test_ecc.py):
   * any single flipped bit per codeword (data OR parity byte) -> corrected
@@ -46,7 +51,6 @@ for _k in range(7):
         if (_DATA_POS[_i] >> _k) & 1:
             _PHYS_MASK[_k, _i // 8] |= np.uint8(1 << (_i % 8))
 
-DATA_POS = jnp.asarray(_DATA_POS)                       # (64,) int32
 PHYS_MASK = jnp.asarray(_PHYS_MASK)                     # (7, 8) uint8
 
 PARITY_OVERHEAD = 1.0 / 8.0  # parity bytes per weight byte
@@ -106,46 +110,51 @@ def check_and_correct(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Detect + correct single-bit errors per codeword.
 
+    One pass over the bytes: each byte maps to an 8-bit contribution (bit
+    j < 7: parity of ``byte & phys_mask[j, row]``; bit 7: parity of the
+    byte), the 8 contributions of a codeword XOR-reduce to its computed
+    parity byte, and the flipped bit's place follows from the syndrome by
+    arithmetic. Nothing larger than the weight is materialised.
+
     Args:
       raw_bytes: (K, N) uint8 received weight bytes (possibly corrupted).
       parity:    (K//8, N) uint8 received parity plane (possibly corrupted).
       phys_mask/data_pos: optional codec tables (see ``tables()``); passed
-        explicitly when called inside a Pallas kernel.
+        explicitly when called inside a Pallas kernel. ``data_pos`` is
+        implied by the arithmetic corrector and unused.
     Returns:
       corrected: (K, N) uint8 — data with single-bit errors repaired.
       dirty:     (K//8, N) bool — codeword had a detected error (incl. parity-only).
       uncorrectable: (K//8, N) bool — double-bit (or worse) error detected.
     """
+    del data_pos
     if phys_mask is None:
         phys_mask = PHYS_MASK
-    if data_pos is None:
-        data_pos = DATA_POS
     k, n = raw_bytes.shape
     cw = _as_codewords(raw_bytes)                                    # (G, 8, N)
-    masked = cw[:, None, :, :] & phys_mask[None, :, :, None]         # (G, 7, 8, N)
-    pk = (jnp.sum(_byte_parity(masked).astype(jnp.int32), axis=2) & 1)  # (G,7,N)
-    stored_pk = (parity[:, None, :] >> jnp.arange(7, dtype=jnp.uint8)[None, :, None]) & 1
-    s_bits = pk.astype(jnp.uint8) ^ stored_pk.astype(jnp.uint8)      # (G, 7, N)
-    syndrome = jnp.sum(
-        s_bits.astype(jnp.int32) << jnp.arange(7, dtype=jnp.int32)[None, :, None], axis=1
-    )                                                                # (G, N) 0..127
-    data_par = jnp.sum(_byte_parity(cw).astype(jnp.int32), axis=1) & 1
-    stored_hamming_par = jnp.sum(stored_pk.astype(jnp.int32), axis=1) & 1
-    overall_recv = ((parity >> jnp.uint8(7)) & 1).astype(jnp.int32)
-    dq = (data_par + stored_hamming_par + overall_recv) & 1          # (G, N) 0/1
-
-    # Single-bit data error at physical bit i iff dq==1 and syndrome==data_pos[i].
+    parity = parity.astype(jnp.uint8)          # ``encode`` hands back uint32
+    one = jnp.uint8(1)
+    contrib = (lax.population_count(cw) & one) << 7
+    for j in range(7):
+        masked = cw & phys_mask[j][None, :, None]
+        contrib = contrib | ((lax.population_count(masked) & one) << j)
+    computed = lax.reduce(contrib, jnp.uint8(0), lax.bitwise_xor, (1,))  # (G, N)
+    syndrome = (computed ^ parity) & jnp.uint8(0x7F)                 # (G, N) 0..127
+    # Overall parity over data, stored Hamming bits and stored overall bit.
+    dq = lax.population_count((computed & jnp.uint8(0x80)) ^ parity) & one
     is_err = dq.astype(bool)
-    onehot = is_err[:, None, :] & (syndrome[:, None, :] == data_pos[None, :, None])
-    flip = jnp.sum(
-        onehot.reshape(k // 8, 8, 8, n).astype(jnp.uint8)
-        * _bit_weights()[None, None, :, None],
-        axis=2,
-    ).astype(jnp.uint8)                                              # (G, 8, N)
+    is_power = (syndrome & (syndrome - one)) == 0                    # incl. syndrome==0
+    # dq==1 and a data position (not a power of two, <= 71): flip that bit.
+    # Logical position p holds physical data bit p - floor(log2 p) - 2.
+    data_hit = is_err & ~is_power & (syndrome <= jnp.uint8(71))
+    log2 = jnp.uint8(7) - lax.clz(syndrome)
+    bit = syndrome - log2 - jnp.uint8(2)
+    row = jnp.where(data_hit, bit >> 3, jnp.uint8(8))                # 8: no flip
+    rows = jnp.arange(8, dtype=jnp.uint8)[None, :, None]
+    flip = jnp.where(row[:, None, :] == rows,
+                     one << (bit & jnp.uint8(7))[:, None, :], jnp.uint8(0))
     corrected = (cw ^ flip).reshape(k, n)
 
-    is_power = (syndrome & (syndrome - 1)) == 0                      # incl. syndrome==0
-    data_hit = jnp.any(onehot, axis=1)                               # (G, N)
     # dq==1: correctable iff syndrome hits a data position, a parity position
     # (power of two) or 0 (overall-bit flip). dq==0 & syndrome!=0: double error.
     uncorrectable = (~is_err & (syndrome != 0)) | (is_err & ~data_hit & ~is_power)

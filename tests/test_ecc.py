@@ -94,3 +94,45 @@ def test_low_rber_full_recovery():
     cw_ok = ok.reshape(-1, 8, ok.shape[1]).all(axis=1)
     assert bool(np.all(cw_ok | unc_np))
     assert unc_np.mean() < 1e-3
+
+
+
+_RANDOM_CASES = [((k, n), nflips, None)
+                 for k, n in [(8, 128), (64, 384), (2048, 256)]
+                 for nflips in [0, 1, 2, 3, 64, 5000]]
+# Each of the 72 bits of codeword (0, column 5) of an (8, 128) matrix: bit
+# b*8+i < 64 is bit i of row b, bits 64..71 are the parity byte's.
+_SWEEP_CASES = [((8, 128), 1, bit) for bit in range(72)]
+
+
+@pytest.mark.parametrize(
+    "shape,nflips,bit", _RANDOM_CASES + _SWEEP_CASES,
+    ids=[f"{k}x{n}-flips{f}" for (k, n), f, _ in _RANDOM_CASES]
+    + [f"sweep-bit{b}" for _, _, b in _SWEEP_CASES])
+def test_check_and_correct_bit_identical_to_np(shape, nflips, bit):
+    """The device codec agrees with the host port on corrected bytes,
+    ``dirty`` and ``uncorrectable``: random flips over data and parity
+    bytes, and each single bit of one codeword (``bit``)."""
+    k, n = shape
+    rng = np.random.default_rng(k * 7 + n + nflips)
+    raw = rng.integers(0, 256, (k, n), dtype=np.uint8)
+    parity = np.asarray(ecc.encode(jnp.asarray(raw))).astype(np.uint8)
+    # Bit positions over the data bytes followed by the parity bytes.
+    if bit is None:
+        pos = rng.choice((raw.size + parity.size) * 8, nflips, replace=False)
+    elif bit < 64:
+        pos = np.array([(bit // 8 * n + 5) * 8 + bit % 8])
+    else:
+        pos = np.array([(raw.size + 5) * 8 + bit - 64])
+    flat = np.concatenate([raw.reshape(-1), parity.reshape(-1)])
+    np.bitwise_xor.at(flat, pos // 8, (1 << (pos % 8)).astype(np.uint8))
+    bad = flat[:raw.size].reshape(raw.shape)
+    bad_parity = flat[raw.size:].reshape(parity.shape)
+
+    want = ecc.check_and_correct_np(bad, bad_parity)
+    got = ecc.check_and_correct(jnp.asarray(bad), jnp.asarray(bad_parity))
+    for w, g, what in zip(want, got, ("corrected", "dirty", "uncorrectable")):
+        np.testing.assert_array_equal(np.asarray(g), w, err_msg=what)
+    if bit is not None:                   # one flip: repaired and flagged
+        np.testing.assert_array_equal(want[0], raw)
+        assert int(want[1].sum()) == 1 and not want[2].any()

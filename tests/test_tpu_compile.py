@@ -96,12 +96,12 @@ CASES = {
 REFUSED = {
     "paged_ecdp_matmul_pallas_ecc": (
         lambda: _ffn_paged_pallas(True),
-        "Reductions over unsigned integers not implemented"),
+        "Unimplemented primitive in Pallas TPU lowering .*: reduce"),
     "ecdp_matmul_pallas_ecc": (
         lambda: _ffn_resident(ecdp_matmul_pallas, block_m=M, block_k=512,
                               block_n=512, ecc_enabled=True,
                               interpret=False),
-        "Reductions over unsigned integers not implemented"),
+        "Unimplemented primitive in Pallas TPU lowering .*: reduce"),
     "decode_attn_pallas": (
         _decode_attn,
         "requires that rank 1 block shapes"),
@@ -123,6 +123,22 @@ def test_main_path_kernel_compiles_for_v5e(name, one_chip):
     compiled = _compile(CASES[name], one_chip)
     if "pallas" in name:
         assert "tpu_custom_call" in compiled.as_text()
+
+
+# `bytes accessed` of the ecdp_matmul_xla_ecc compile when the check built
+# (G,7,8,N) masked bytes, summed them in int32 and placed the flip with a
+# (G,64,N) one-hot compare; the one-pass check reads under half of it.
+EXPANDED_ECC_BYTES = 470_571_008
+
+
+def test_ecc_matmul_reads_weight_bytes_once_for_v5e(one_chip):
+    compiled = _compile(CASES["ecdp_matmul_xla_ecc"], one_chip)
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < EXPANDED_ECC_BYTES / 2, cost
+    text = compiled.as_text()
+    assert not re.search(r"\bu8\[[\d,]*\b7,8\b", text), "(G,7,8,N) bytes"
+    assert not re.search(r"\bs32\[[\d,]*\b7,", text), "(G,7,N) int32 sums"
 
 
 @pytest.mark.parametrize("name", [
