@@ -98,7 +98,7 @@ def encode(raw_bytes: jnp.ndarray, phys_mask: jnp.ndarray | None = None) -> jnp.
     data_par = jnp.sum(_byte_parity(cw).astype(jnp.int32), axis=1) & 1  # (G, N)
     par_par = jnp.sum(pk, axis=1) & 1
     overall = ((data_par + par_par) & 1).astype(jnp.uint8) << jnp.uint8(7)
-    return hamming | overall
+    return (hamming | overall).astype(jnp.uint8)
 
 
 @jax.named_scope("ecc")        # nests under the matmul that reads the weight
@@ -132,7 +132,7 @@ def check_and_correct(
         phys_mask = PHYS_MASK
     k, n = raw_bytes.shape
     cw = _as_codewords(raw_bytes)                                    # (G, 8, N)
-    parity = parity.astype(jnp.uint8)          # ``encode`` hands back uint32
+    parity = parity.astype(jnp.uint8)
     one = jnp.uint8(1)
     contrib = (lax.population_count(cw) & one) << 7
     for j in range(7):

@@ -14,6 +14,7 @@ NAND reads.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
 import zlib
 from typing import Any, Callable
@@ -125,22 +126,52 @@ def tier_of(path: str, patterns=DEFAULT_FLASH_PATTERNS) -> str:
     return FLASH if is_flash_path(path, patterns) else DRAM
 
 
-def encode_flash(w: jnp.ndarray, rber: float = 0.0, seed: int = 0) -> FlashWeight:
-    """Quantize + ECC-encode one weight matrix (leading dims = layer stack)."""
-    if w.ndim < 2:
-        raise ValueError("flash tier holds matrices")
+@jax.jit
+def _encode_matrices(w: jnp.ndarray):
+    """Quantize + parity of a stack of matrices (..., K, N), one matrix at a
+    time for the parity pass (``lax.map``), so no codec intermediate is
+    larger than one matrix's."""
     q, scale = quantize_int8(w, axis=-2)
     raw = ecc.weights_to_bytes(q)
-    lead = raw.shape[:-2]
-    flat = raw.reshape((-1,) + raw.shape[-2:]) if lead else raw[None]
-    pars = []
-    for i in range(flat.shape[0]):
-        pars.append(ecc.encode(flat[i]))
-    parity = jnp.stack(pars).reshape(lead + pars[0].shape) if lead else pars[0]
+    flat = raw.reshape((-1,) + raw.shape[-2:])
+    parity = jax.lax.map(ecc.encode, flat)
+    return q, parity.reshape(raw.shape[:-2] + parity.shape[-2:]), scale
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_slice(out, i, w):
+    """Encode ``w[i]`` into slice ``i`` of the preallocated ``out``, in
+    place (``out`` is donated)."""
+    part = _encode_matrices(w[i])
+    return tuple(o.at[i].set(p) for o, p in zip(out, part))
+
+
+def encode_flash(w: jnp.ndarray, rber: float = 0.0, seed: int = 0) -> FlashWeight:
+    """Quantize + ECC-encode one weight matrix (leading dims = layer stack).
+
+    A stacked leaf is encoded one leading slice at a time into outputs
+    allocated once and updated in place. Quantization is per output
+    channel within a matrix, so the slices are independent and the result
+    is the whole-stack encoding bit for bit, while deploy never holds more
+    than one slice in float32."""
+    if w.ndim < 2:
+        raise ValueError("flash tier holds matrices")
+    if w.ndim == 2:
+        q, parity, scale = _encode_matrices(w)
+    else:
+        k, n = w.shape[-2:]
+        lead = w.shape[:-2]
+        out = (jnp.zeros(w.shape, jnp.int8),
+               jnp.zeros(lead + (k // 8, n), jnp.uint8),
+               jnp.zeros(lead + (1, n), jnp.float32))
+        for i in range(lead[0]):
+            out = _put_slice(out, jnp.int32(i), w)
+        q, parity, scale = out
     if rber > 0.0:
+        raw = ecc.weights_to_bytes(q)
         corrupted, _ = ecc.inject_bit_errors_np(np.asarray(raw), rber, seed)
-        raw = jnp.asarray(corrupted)
-    return FlashWeight(q=ecc.bytes_to_weights(raw), parity=parity, scale=scale)
+        q = ecc.bytes_to_weights(jnp.asarray(corrupted))
+    return FlashWeight(q=q, parity=parity, scale=scale)
 
 
 def deploy(

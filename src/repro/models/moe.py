@@ -20,8 +20,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.erdpe import maybe_flash_matmul
+from repro.core import ecc
+from repro.core.erdpe import maybe_flash_matmul, serve_ecc_mode
 from repro.core.tiering import FlashWeight, PagedWeight
+from repro.kernels import paged_ffn
 from repro.models import common as cm
 from repro.models import dense
 
@@ -75,34 +77,16 @@ def _expert_matmul(x, w, out_dtype=None):
     tensor-parallel psum (summing bf16-rounded partials doubles error);
     None = the legacy dtype (bf16 on flash tiers, x.dtype on arrays)."""
     g, e, c, k = x.shape
-    if isinstance(w, PagedWeight):
-        # Pool-paged expert bank (streamed serving): per-expert XLA gather
-        # fallback — dense weight rebuilt from the shared pool snapshot,
-        # then the identical resident ECDP math, so slab-vs-resident parity
-        # is exact. (The Pallas paged kernel is exercised per-expert in
-        # tests/test_paged_ffn.py; the engine's CPU path is XLA.)
-        from repro.kernels import ops
-        xe = x.transpose(1, 0, 2, 3).reshape(e, g * c, k).astype(jnp.float32)
-        kn = tuple(w.kn)
-
-        def one(xg, tbl, ps, ss):
-            # ecc_enabled=False mirrors the FlashWeight branch below: the
-            # expert bank serves raw bytes (correction folds in at deploy)
-            return ops.paged_ecdp_matmul_xla(xg, w.pool, tbl, ps, ss, kn,
-                                             ecc_enabled=False)
-
-        out = jax.vmap(one)(xe, w.q_tbl, w.p_slots, w.s_slots)
-        n = out.shape[-1]
-        return out.reshape(e, g, c, n).transpose(1, 0, 2, 3).astype(
-            out_dtype or jnp.bfloat16)
     if isinstance(w, FlashWeight):
-        # Per-expert ERDPE over the stacked bank (XLA path: correction math
-        # folds into the einsum; Pallas path is exercised per-expert in tests).
+        # Per-expert ERDPE over the stacked bank, checked on read under
+        # REPRO_SERVE_ECC=inline as every other flash-tier read is.
         from repro.kernels import ops
         xe = x.transpose(1, 0, 2, 3).reshape(e, g * c, k).astype(jnp.float32)
+        ecc_inline = serve_ecc_mode() == "inline"
 
         def one(xg, qe, pe, se):
-            return ops.ecdp_matmul_xla(xg, qe, pe, se)
+            return ops.ecdp_matmul_xla(xg, qe, pe, se,
+                                       ecc_enabled=ecc_inline)
 
         out = jax.vmap(one)(xe, w.q, w.parity, w.scale)
         n = out.shape[-1]
@@ -197,12 +181,15 @@ def moe_apply(cfg, p, x, capacity_factor: float = 1.25):
 #
 # The serving engine's mixed batch is tiny ((n_slots, chunk_tokens) lanes),
 # so the capacity-dispatch machinery above (built for sharded training
-# shapes) gives way to a LOSSLESS dispatch: every (token, k) assignment owns
-# its own column of the expert buffer, so no capacity trash row exists and —
-# critically for streamed serving — each expert's computation is independent
-# of the bank's composition: a partial SLAB holding only the ROUTED experts
-# (plus a row map) produces bit-identical outputs to the full resident bank.
-# That independence is what makes streamed-vs-resident greedy parity exact.
+# shapes) gives way to a LOSSLESS ROUTED-ONLY dispatch: the experts that
+# valid assignments route to are gathered into a compact slab (ascending
+# expert id, a static row bound picked by ``lax.switch``), the assignments
+# are sorted by slab row, and one grouped product per weight kind
+# (``lax.ragged_dot``) does work in proportion to the assignments. Each
+# expert's computation is independent of the bank's composition, so a
+# partial SLAB holding only the routed experts (plus a row map) produces
+# bit-identical outputs to the full resident bank — what makes
+# streamed-vs-resident greedy parity exact.
 
 
 def serve_route(router, x, top_k: int, n_groups: int = 1,
@@ -240,50 +227,188 @@ def serve_route(router, x, top_k: int, n_groups: int = 1,
     return jax.nn.softmax(gates, axis=-1), idx.astype(jnp.int32)
 
 
-def serve_expert_ffn(bank, x, gates, idx, slab_map=None, axis_name=None):
-    """Batched-expert SwiGLU over a full or partial expert bank.
+def slab_bounds(n_experts: int, top_k: int) -> tuple[int, ...]:
+    """Static row counts of the routed-expert slab: the powers of two from
+    the least that holds one token's ``top_k`` experts up to below
+    ``n_experts``, then ``n_experts`` — one compiled branch each (5 for
+    128 experts, top-8)."""
+    b = 1
+    while b < top_k:
+        b *= 2
+    out = []
+    while b < n_experts:
+        out.append(b)
+        b *= 2
+    return tuple(out) + (n_experts,)
+
+
+def expert_counts(idx, ok, n_experts: int):
+    """(n_experts,) i32: the ``ok`` assignments routed to each expert
+    (a one-hot sum: no scatter for the compiler to merge with another)."""
+    hit = jax.nn.one_hot(idx.reshape(-1), n_experts, dtype=jnp.int32)
+    return jnp.sum(hit * ok.reshape(-1, 1).astype(jnp.int32), axis=0)
+
+
+def slab_ids(present, bound: int):
+    """The ``bound`` expert ids a slab holds: every routed (``present``)
+    expert in ascending id order, then unrouted fillers; the whole bank in
+    id order when ``bound`` is the expert count."""
+    n = present.shape[0]
+    if bound == n:
+        return jnp.arange(n, dtype=jnp.int32)
+    return jnp.argsort(~present, stable=True)[:bound].astype(jnp.int32)
+
+
+def _slab_weight(w, rows, ecc_inline: bool, layer=None):
+    """(int8-valued weights (B, K, N) bf16, scales (B, 1, N) f32 or None)
+    of bank ``rows`` — the only expert bytes the step reads. A deployed
+    bank is ECC-checked on read when ``ecc_inline``; a pool-paged slab
+    keeps the store's host-side check. ``layer`` indexes a deployed bank
+    still stacked over layers (L, E, K, N), so that a layer's whole bank
+    is never sliced out before its routed rows are."""
+    if isinstance(w, PagedWeight):
+        k, n = w.kn
+        q = jax.vmap(lambda t: paged_ffn.gather_q(w.pool, t, k, n))(
+            w.q_tbl[rows])
+        scale = jax.vmap(lambda t: paged_ffn.gather_scale(w.pool, t, n))(
+            w.s_slots[rows])
+        return q.astype(jnp.bfloat16), scale
+    if isinstance(w, FlashWeight):
+        lead = () if layer is None else (layer,)
+
+        def read(q, parity):
+            if not ecc_inline:
+                return q
+            raw, _, _ = ecc.check_and_correct(ecc.weights_to_bytes(q), parity)
+            return ecc.bytes_to_weights(raw)
+
+        if rows.shape[0] == w.q.shape[-3]:         # the whole bank, id order
+            q = jax.vmap(read)(w.q[lead], w.parity[lead])
+            scale = w.scale[lead]
+        else:
+            # one dynamic slice per routed row, checked as it is read: a
+            # row gather over the stacked bank lowers to a pass over all of
+            # it, and checking the joined rows copies them first
+            q = jnp.stack([read(w.q[lead + (r,)], w.parity[lead + (r,)])
+                           for r in rows])
+            scale = jnp.stack([w.scale[lead + (r,)] for r in rows])
+        return q.astype(jnp.bfloat16), scale
+    return w[rows].astype(jnp.bfloat16), None
+
+
+def _grouped(xs, w, grp, group_sizes, out_dtype):
+    """Rows of ``xs`` (sorted by slab row) times their expert's weight."""
+    q, scale = w
+    out = jax.lax.ragged_dot(xs.astype(jnp.bfloat16), q, group_sizes,
+                             preferred_element_type=jnp.float32)
+    if scale is not None:
+        out = out * scale[grp, 0]
+    return out.astype(out_dtype)
+
+
+def routed_ffn(bank, x_flat, flat_e, ok, counts, slab_map, *, bound: int,
+               top_k: int, ecc_inline: bool, down_dtype=jnp.bfloat16,
+               layer=None):
+    """One slab bound of ``serve_expert_ffn``: the (A, D) f32 expert
+    outputs, in assignment order (0 where not ``ok``), reading only the
+    ``bound`` slab rows. ``x_flat`` (tokens, D); ``flat_e``/``ok`` (A,)
+    with A = tokens * top_k; ``counts`` from ``expert_counts``."""
+    n_exp = counts.shape[0]
+    with jax.named_scope("route"):
+        present = counts > 0
+        ids = slab_ids(present, bound)
+        # slab row of each expert: its id in the whole bank, else its rank
+        # among the routed ones
+        pos = (jnp.arange(n_exp, dtype=jnp.int32) if bound == n_exp
+               else jnp.cumsum(present.astype(jnp.int32)) - 1)
+        key = jnp.where(ok, pos[flat_e], bound)
+        order = jnp.argsort(key, stable=True)
+        grp = jnp.minimum(key[order], bound - 1)
+        xs = x_flat[order // top_k]
+        sizes = counts[ids]
+    with jax.named_scope("experts"):
+        rows = ids if slab_map is None else jnp.maximum(slab_map[ids], 0)
+        w = {name: _slab_weight(bank[name], rows, ecc_inline, layer)
+             for name in ("w_gate", "w_up", "w_down")}
+        h_g = _grouped(xs, w["w_gate"], grp, sizes, jnp.bfloat16)
+        h_u = _grouped(xs, w["w_up"], grp, sizes, jnp.bfloat16)
+        h = (jax.nn.silu(h_g.astype(jnp.float32))
+             * h_u.astype(jnp.float32)).astype(x_flat.dtype)
+        y = _grouped(h, w["w_down"], grp, sizes, down_dtype)
+    with jax.named_scope("route"):
+        y = jnp.where(ok[order][:, None], y.astype(jnp.float32), 0.0)
+        return y[jnp.argsort(order)]                   # back to assignments
+
+
+def serve_expert_ffn(bank, x, gates, idx, slab_map=None, axis_name=None,
+                     valid=None, layer=None, with_counts=False):
+    """Routed-only SwiGLU over a full or partial expert bank.
 
     bank     : {"w_gate","w_up","w_down"} each (E_bank, K, N) FlashWeight
-               (deployed) or plain array; E_bank = n_experts for the
-               resident engine, the device slab size for the streamed one.
+               (deployed), PagedWeight (pool-paged slab) or plain array;
+               E_bank = n_experts for the resident engine, the device slab
+               size for the streamed one.
     x        : (S, T, D) normed FFN input; gates/idx: (S, T, k).
     slab_map : (n_experts,) i32 expert-id -> bank row, -1 = not resident
-               (those assignments contribute 0 — the engine only leaves an
-               expert unmapped for padding lanes, whose output is never
-               read). None = identity (bank row e holds expert e).
+               (those assignments contribute 0). None = identity (bank row
+               e holds expert e).
     axis_name: tensor-parallel expert FFN inside a shard_map — each shard's
                slab holds the expert's d_ff/n_shards columns (gate/up
                column-parallel, down row-parallel over the same slice), so
                the down output is PARTIAL; kept f32 through the gate-
                weighted combine (all linear) and completed by ONE psum.
+    valid    : (S, T) bool lanes that carry a token; padding lanes route
+               nothing (None = every lane).
+    layer    : index into a deployed bank stacked over layers, (L, E, K, N)
+               (the resident step passes the whole stack: a layer sliced
+               out before the branch that reads it is copied whole).
+    with_counts: also return ``expert_counts`` of the valid assignments,
+               (n_experts,) i32 — the step's MoE counters read them.
+
+    Only the experts that valid assignments route to are read: their rows
+    are gathered into a slab of ``slab_bounds(n_experts, k)`` rows (the
+    least bound holding them, chosen in-graph), a deployed bank's rows
+    are ECC-checked there under ``REPRO_SERVE_ECC=inline``, and each
+    weight kind is one grouped product over the assignments sorted by
+    slab row.
     """
     s, t, d = x.shape
     k = idx.shape[-1]
     a = s * t * k
-    row = idx if slab_map is None else slab_map[idx]          # (S, T, k)
-    flat_row = row.reshape(a)
-    ok = flat_row >= 0
-    # assignment a = token * k + j owns column a: scatter collisions are
-    # impossible, so dispatch loses nothing and needs no sort.
-    xa = jnp.repeat(x.reshape(s * t, d), k, axis=0)           # (A, D)
-    cols = jnp.arange(a)
-    e_bank = bank["w_gate"].shape[0]
-    buf = jnp.zeros((e_bank, a, d), x.dtype)
-    buf = buf.at[jnp.where(ok, flat_row, 0), cols].set(
-        jnp.where(ok[:, None], xa, 0).astype(x.dtype))
-    bb = buf[None]                                            # (1, E, A, D)
-    h_g = _expert_matmul(bb, bank["w_gate"])
-    h_u = _expert_matmul(bb, bank["w_up"])
-    h = (jax.nn.silu(h_g.astype(jnp.float32))
-         * h_u.astype(jnp.float32)).astype(x.dtype)
-    down_dtype = jnp.float32 if axis_name is not None else None
-    out_buf = _expert_matmul(h, bank["w_down"], down_dtype)[0]  # (E, A, D)
-    out_a = out_buf[jnp.where(ok, flat_row, 0), cols].astype(jnp.float32)
-    out_a = jnp.where(ok[:, None], out_a, 0.0)
-    out = (out_a * gates.reshape(a)[:, None]).reshape(s, t, k, d).sum(axis=2)
-    if axis_name is not None:
-        out = jax.lax.psum(out, axis_name)
-    return out.astype(x.dtype)
+    n_exp = bank["w_gate"].shape[-3] if slab_map is None \
+        else slab_map.shape[0]
+    flat_e = idx.reshape(a)
+    ok = jnp.ones((a,), bool) if valid is None else jnp.repeat(
+        valid.reshape(s * t), k)
+    if slab_map is not None:
+        ok = ok & (slab_map[flat_e] >= 0)
+    ecc_inline = serve_ecc_mode() == "inline"
+    down_dtype = jnp.float32 if axis_name is not None else jnp.bfloat16
+    x_flat = x.reshape(s * t, d)
+
+    with jax.named_scope("route"):
+        counts = expert_counts(flat_e, ok, n_exp)
+        bounds = slab_bounds(n_exp, k)
+        pick = jnp.searchsorted(jnp.asarray(bounds, jnp.int32),
+                                jnp.sum((counts > 0).astype(jnp.int32)))
+
+    def branch(bound):
+        return lambda _: routed_ffn(
+            bank, x_flat, flat_e, ok, counts, slab_map, bound=bound,
+            top_k=k, ecc_inline=ecc_inline, down_dtype=down_dtype,
+            layer=layer)
+
+    if isinstance(pick, jax.core.Tracer):
+        out_a = jax.lax.switch(pick, [branch(b) for b in bounds], None)
+    else:                        # eager: trace only the bound in use
+        out_a = branch(bounds[int(pick)])(None)
+    with jax.named_scope("route"):
+        out = (out_a * gates.reshape(a)[:, None]).reshape(s, t, k, d).sum(
+            axis=2)
+        if axis_name is not None:
+            out = jax.lax.psum(out, axis_name)
+        out = out.astype(x.dtype)
+    return (out, counts) if with_counts else out
 
 
 def _layer_fwd(cfg, x, lp, positions, collect_kv=True):

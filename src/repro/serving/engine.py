@@ -74,6 +74,14 @@ from repro.serving.prefix import PrefixIndex, block_hashes
 from repro.serving.sampler import SampleConfig, last_valid_hidden, sample
 
 
+# Every serving program rounds to bfloat16 exactly where it says so: XLA
+# may otherwise keep a fused intermediate wider, so that the served tokens
+# would hang on fusion decisions — and a sparse-expert router, which flips
+# on the last bit of a near tie, would route as no stated precision does.
+_jit = functools.partial(
+    jax.jit, compiler_options={"xla_allow_excess_precision": False})
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -186,24 +194,34 @@ def _moe_attn_router_body(cfg, exec_mode, lengths, positions, block_tables,
                         None)
     x = x + out
     h = dense._norm(cfg, x, lp, "ln2")
-    gates, idx = moe_mod.serve_route(
-        lp["moe"]["router"], h, cfg.top_k,
-        n_groups=getattr(cfg, "n_expert_groups", 1),
-        topk_groups=getattr(cfg, "topk_expert_groups", 0))
+    with jax.named_scope("route"):
+        gates, idx = moe_mod.serve_route(
+            lp["moe"]["router"], h, cfg.top_k,
+            n_groups=getattr(cfg, "n_expert_groups", 1),
+            topk_groups=getattr(cfg, "topk_expert_groups", 0))
     return x, h, gates, idx, k, v
 
 
 def _chunk_layer_moe(cfg, exec_mode, lengths, positions, block_tables,
-                     x, layer):
+                     valid, experts, x, layer):
     """One mixed-batch MoE layer (resident data plane): the shared
-    attention+router body + the expert FFN over the full deployed bank
-    (``slab_map=None`` — the streamed expert half's degenerate case).
-    ``layer`` = (params slice, read-only paged K/V pool slices)."""
-    lp, kc, vc = layer
+    attention+router body + the routed-only expert FFN over the deployed
+    bank (``slab_map=None`` — the streamed expert half's degenerate case).
+    ``layer`` = (params slice without the expert bank, read-only paged K/V
+    pool slices, layer index); ``experts`` is the bank stacked over all
+    layers, indexed by the layer only where routed rows are read.
+    ``valid`` (slots, T) marks the lanes that carry a token — padding
+    lanes route nothing. Also returns the layer's valid assignments and
+    the distinct experts they route to (the step's MoE counters)."""
+    lp, kc, vc, li = layer
     x, h, gates, idx, k, v = _moe_attn_router_body(
         cfg, exec_mode, lengths, positions, block_tables, x, lp, kc, vc)
-    x = _moe_expert_impl(x, h, gates, idx, lp["moe"]["experts"], None)
-    return x, (k, v)
+    with jax.named_scope("ffn"):
+        y, counts = moe_mod.serve_expert_ffn(experts, h, gates, idx,
+                                             valid=valid, layer=li,
+                                             with_counts=True)
+    routed = jnp.sum((counts > 0).astype(jnp.int32))
+    return x + y, (k, v, jnp.sum(counts), routed)
 
 
 def _moe_attn_router_impl(cfg, exec_mode, layers_dram, k_pool, v_pool, x,
@@ -222,10 +240,10 @@ def _moe_attn_router_impl(cfg, exec_mode, layers_dram, k_pool, v_pool, x,
 
 
 def _moe_expert_impl(x, h, gates, idx, slab, slab_map):
-    """Expert half of one STREAMED MoE layer: the batched-expert FFN over
-    the device SLAB holding only the routed (resident/fetched) experts.
-    Same math as the resident bank — per-expert computation is independent
-    of bank composition, so slab-vs-full-bank parity is exact."""
+    """Expert half of one STREAMED MoE layer: the routed-only expert FFN
+    over the device SLAB holding only the routed (resident/fetched)
+    experts. Per-expert computation is independent of bank composition,
+    so slab-vs-full-bank parity is exact."""
     with jax.named_scope("ffn"):
         return x + moe_mod.serve_expert_ffn(slab, h, gates, idx, slab_map)
 
@@ -499,9 +517,14 @@ def _step_impl(cfg, sched_cfg, sample_cfg, kv_aware, exec_mode, unroll,
     if cfg.family == "moe":
         # MoE projections stay on the NPU (no flash attn copy to dispatch
         # to), so the resident layer body drops the bitmap/flash operands.
+        valid = jnp.arange(tokens.shape[1])[None, :] < q_lens[:, None]
+        layers = params["layers"]
+        experts = layers["moe"]["experts"]
+        layers = {**layers, "moe": {k: v for k, v in layers["moe"].items()
+                                    if k != "experts"}}
         body = functools.partial(_chunk_layer_moe, cfg, exec_mode, ctx_lens,
-                                 positions, block_tables)
-        xs = (params["layers"], state["k"], state["v"])
+                                 positions, block_tables, valid, experts)
+        xs = (layers, state["k"], state["v"], jnp.arange(cfg.n_layers))
     else:
         body = functools.partial(_chunk_layer, cfg, exec_mode, bitmap,
                                  ctx_lens, positions, block_tables)
@@ -509,20 +532,25 @@ def _step_impl(cfg, sched_cfg, sample_cfg, kv_aware, exec_mode, unroll,
     with jax.named_scope("layers"):
         if unroll:
             # eager reference: interpreted Python loop over layers
-            ks, vs = [], []
+            outs = []
             for li in range(cfg.n_layers):
-                x, (kl, vl) = body(x, jax.tree.map(lambda a: a[li], xs))
-                ks.append(kl)
-                vs.append(vl)
-            k_new, v_new = jnp.stack(ks), jnp.stack(vs)  # (L, S, T, KV, Dh)
+                x, out = body(x, jax.tree.map(lambda a: a[li], xs))
+                outs.append(out)
+            per_layer = jax.tree.map(lambda *a: jnp.stack(a), *outs)
         else:
-            x, (k_new, v_new) = jax.lax.scan(body, x, xs)
+            x, per_layer = jax.lax.scan(body, x, xs)
+    k_new, v_new = per_layer[:2]                       # (L, S, T, KV, Dh)
 
-    return _finish_step(cfg, sched_cfg, sample_cfg, kv_aware, spec_k,
-                        params["final_norm"], params["lm_head"], state, x,
-                        k_new, v_new, q_lens, admitted, positions,
-                        block_tables, key, drafts=drafts, n_draft=n_draft,
-                        is_decode=is_decode)
+    out = _finish_step(cfg, sched_cfg, sample_cfg, kv_aware, spec_k,
+                       params["final_norm"], params["lm_head"], state, x,
+                       k_new, v_new, q_lens, admitted, positions,
+                       block_tables, key, drafts=drafts, n_draft=n_draft,
+                       is_decode=is_decode)
+    if cfg.family == "moe":
+        # summed over layers, read with the step's one stats transfer
+        out[-1]["moe_assignments"] = jnp.sum(per_layer[2])
+        out[-1]["moe_experts_routed"] = jnp.sum(per_layer[3])
+    return out
 
 
 def _paged(pool_buf, tbl, kn):
@@ -769,6 +797,12 @@ class Engine:
         self._c_kv_used = self.obs.counter(
             "engine_kv_rows_used_total",
             "per step, KV rows the active requests hold")
+        self._c_moe_assign = self.obs.counter(
+            "engine_moe_assignments_total",
+            "token->expert assignments of valid lanes, summed over layers")
+        self._c_moe_routed = self.obs.counter(
+            "engine_moe_experts_routed_total",
+            "distinct experts routed by valid lanes, summed over layers")
         self._phases: dict[str, float] = {}
         # per-slot token histories feeding the in-graph drafter (spec mode)
         if spec_cfg is not None:
@@ -801,7 +835,7 @@ class Engine:
             # update of device-resident serving state. (CPU ignores donation
             # and warns, so only donate where it lands.)
             donate = (2,) if jax.default_backend() != "cpu" else ()
-            self._step_fn = jax.jit(serve_step, donate_argnums=donate)
+            self._step_fn = _jit(serve_step, donate_argnums=donate)
         else:
             self._step_fn = step
 
@@ -1450,9 +1484,9 @@ class Engine:
             return finish(*args)
 
         donate = (2,) if jax.default_backend() != "cpu" else ()
-        self._embed_fn = jax.jit(embed_fn, **jit_kw)
-        self._group_fn = jax.jit(group_fn, **jit_kw)
-        self._finish_fn = jax.jit(finish_fn, donate_argnums=donate,
+        self._embed_fn = _jit(embed_fn, **jit_kw)
+        self._group_fn = _jit(group_fn, **jit_kw)
+        self._finish_fn = _jit(finish_fn, donate_argnums=donate,
                                   **jit_kw)
         self._step_fn = self._streamed_step
 
@@ -1576,9 +1610,9 @@ class Engine:
             return tail(*args)
 
         donate = (2,) if jax.default_backend() != "cpu" else ()
-        self._head_fn = jax.jit(head_fn, **jit_kw)
-        self._fused_fn = jax.jit(fused_fn, **jit_kw)
-        self._tail_fn = jax.jit(tail_fn, donate_argnums=donate, **jit_kw)
+        self._head_fn = _jit(head_fn, **jit_kw)
+        self._fused_fn = _jit(fused_fn, **jit_kw)
+        self._tail_fn = _jit(tail_fn, donate_argnums=donate, **jit_kw)
         self._step_fn = self._streamed_step_moe
 
     def _streamed_step_moe(self, params, attn_flash, state, tokens, q_lens,
@@ -2315,6 +2349,9 @@ class Engine:
                     self._finish_request(req, slot)
             st = jax.device_get(stats)
         self._npu_frac = float(st["npu_fraction"])
+        if "moe_assignments" in st:
+            self._c_moe_assign.inc(int(st["moe_assignments"]))
+            self._c_moe_routed.inc(int(st["moe_experts_routed"]))
         entry = {
             "kv_len": int(st["kv_len"]),
             "delta_cycles": int(st["delta_cycles"]),

@@ -109,9 +109,10 @@ def test_stacked_paged_weight_rejected():
 
 
 def test_moe_expert_slab_parity():
-    """The vmapped PagedWeight expert branch (streamed slab) against the
-    resident FlashWeight bank — bank composition must not change math."""
-    from repro.models.moe import _expert_matmul
+    """A pool-paged expert slab (streamed plane) reads the same int8
+    weights and scales as the resident FlashWeight bank, row for row —
+    bank composition must not change math."""
+    from repro.models.moe import _slab_weight
     e, k, n = 3, 128, 64
     ws = [jax.random.normal(jax.random.PRNGKey(i), (k, n), jnp.float32)
           for i in range(e)]
@@ -132,9 +133,9 @@ def test_moe_expert_slab_parity():
                                       for i in range(e)])),
         kn=(k, n))
     bank = jax.tree.map(lambda *xs: jnp.stack(xs), *fws)
-    x = jax.random.normal(jax.random.PRNGKey(9), (2, e, 4, k), jnp.float32)
-    out = _expert_matmul(x, pw)
-    want = _expert_matmul(x, bank)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=2e-2, atol=2e-1)
+    rows = jnp.asarray([2, 0], jnp.int32)
+    q_p, s_p = _slab_weight(pw, rows, ecc_inline=False)
+    q_b, s_b = _slab_weight(bank, rows, ecc_inline=False)
+    np.testing.assert_array_equal(np.asarray(q_p, np.float32),
+                                  np.asarray(q_b, np.float32))
+    np.testing.assert_array_equal(np.asarray(s_p), np.asarray(s_b))

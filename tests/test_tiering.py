@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import ecc
 from repro.core.erdpe import maybe_flash_matmul
@@ -50,6 +51,26 @@ def test_encode_flash_stacked_layers():
         raw = ecc.weights_to_bytes(fw.q[li])
         _, dirty, _ = ecc.check_and_correct(raw, fw.parity[li])
         assert int(dirty.sum()) == 0
+
+
+@pytest.mark.parametrize("shape", [(3, 64, 16), (2, 3, 64, 32)])
+def test_slice_wise_encode_is_bit_identical(shape):
+    """A stacked leaf is encoded a leading slice at a time; the result is
+    the whole-stack encoding bit for bit (quantization is per output
+    channel within a matrix), parity stored one byte per codeword."""
+    from repro.core.quant import quantize_int8
+    w = jax.random.normal(jax.random.PRNGKey(7), shape, jnp.bfloat16)
+    fw = encode_flash(w)
+    q, scale = quantize_int8(w, axis=-2)
+    raw = ecc.weights_to_bytes(q)
+    mats = raw.reshape((-1,) + raw.shape[-2:])
+    parity = jnp.stack([ecc.encode(m) for m in mats]).reshape(
+        shape[:-2] + (shape[-2] // 8, shape[-1]))
+    assert fw.parity.dtype == jnp.uint8
+    np.testing.assert_array_equal(np.asarray(fw.q), np.asarray(q))
+    np.testing.assert_array_equal(np.asarray(fw.parity), np.asarray(parity))
+    np.testing.assert_array_equal(np.asarray(fw.scale).view(np.uint32),
+                                  np.asarray(scale).view(np.uint32))
 
 
 def test_deploy_and_forward_with_rber():

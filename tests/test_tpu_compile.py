@@ -153,3 +153,68 @@ def test_kernel_refused_for_v5e(name, one_chip):
         if re.search(msg, str(e)):
             raise KnownRefusal(msg) from e
         raise
+
+
+# --- the Qwen3-MoE serving step at the benchmark cell's sizes ------------------
+
+def _moe_step_args(sharding):
+    """Abstract resident-step arguments of ``qwen3-moe-30b-a3b`` as the
+    cell serves it: 6 layers, 4 slots, 4096 positions, 16-lane chunks."""
+    import dataclasses
+    from repro.configs.qwen3_moe_30b_a3b import CONFIG
+    from repro.core import scheduler as sched
+    from repro.core.tiering import deploy
+    from repro.models import moe
+    from repro.serving.kvcache import PagedKVPool
+    cfg = dataclasses.replace(CONFIG, n_layers=6, max_seq=4096)
+    S = jax.ShapeDtypeStruct
+    raw = jax.eval_shape(lambda k: moe.init(cfg, k), S((2,), jnp.uint32))
+    params = jax.eval_shape(lambda p: deploy(p)[0], raw)
+    box = []
+
+    def kv():
+        box.append(PagedKVPool(6, 4, 4096, cfg.n_kv_heads, cfg.head_dim))
+        return box[0].device_state()
+    state = dict(jax.eval_shape(kv), bitmap=S((32,), jnp.int32),
+                 prev_cycles=S((), jnp.int32))
+    args = (params, None, state, S((4, 16), jnp.int32), S((4,), jnp.int32),
+            S((4,), jnp.bool_), S(box[0].block_tables.shape, jnp.int32),
+            S((2,), jnp.uint32))
+    on = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=sharding), args)
+    return cfg, sched.SchedulerConfig(column_bytes=cfg.d_model, h=32), on
+
+
+def test_moe_step_compiles_for_v5e_at_cell_sizes(one_chip):
+    """The whole resident step (one program: 5 slab bounds per layer under
+    ``lax.switch``) compiles for the chip and fits its 16 GB beside the
+    deployed model; the decode bound's expert read is a fraction of the
+    whole bank's."""
+    from repro.core.erdpe import ExecMode
+    from repro.models import moe
+    from repro.serving import engine as eng_mod
+    from repro.serving.sampler import SampleConfig
+    cfg, sched_cfg, args = _moe_step_args(one_chip)
+    step = functools.partial(eng_mod._step_impl, cfg, sched_cfg,
+                             SampleConfig(), True, ExecMode.XLA, False, None,
+                             None)
+    compiled = eng_mod._jit(step, donate_argnums=(2,)).lower(*args) \
+        .compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 8 * 2**30
+    assert compiled.as_text().count("ragged-dot") >= 3 * 5
+    bank = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape[1:], a.dtype, sharding=one_chip),
+        args[0]["layers"]["moe"]["experts"])
+    S = jax.ShapeDtypeStruct
+    rest = [S((64, 2048), jnp.bfloat16, sharding=one_chip),
+            S((512,), jnp.int32, sharding=one_chip),
+            S((512,), jnp.bool_, sharding=one_chip),
+            S((128,), jnp.int32, sharding=one_chip)]
+    read = {}
+    for bound in (32, 128):
+        fn = functools.partial(moe.routed_ffn, slab_map=None, bound=bound,
+                               top_k=8, ecc_inline=True)
+        cost = jax.jit(fn).lower(bank, *rest).compile().cost_analysis()
+        read[bound] = (cost[0] if isinstance(cost, list)
+                       else cost)["bytes accessed"]
+    assert read[32] < 0.5 * read[128], read
