@@ -1,9 +1,10 @@
-"""Pallas TPU kernel: slot-paged decode attention over the serving KV pool.
+"""Pallas TPU kernel: decode attention over a slot-contiguous KV cache.
 
-One decode step attends each request slot's single query against that slot's
-contiguous (S_max, KV, Dh) pool region, masked by the slot's live length —
-the data-plane half of the engine's compiled step (DESIGN.md §6). The kernel
-is a flash-decoding-style online softmax:
+One decode step attends each sequence's single query against that
+sequence's contiguous (S_max, KV, Dh) cache region, masked by its live
+length — the models' own decode path (``models/common.decode_attention``;
+the serving engine's block-paged pool is kernels/paged_attn.py's). The
+kernel is a flash-decoding-style online softmax:
 
   * grid = (slots, S blocks); the S axis is innermost so the (block_s, KV,
     Dh) K/V tiles stream HBM->VMEM through the Pallas pipeline while the
@@ -19,7 +20,9 @@ is a flash-decoding-style online softmax:
 The kernel returns the UNNORMALIZED accumulator plus the (m, l) online-
 softmax state so the caller can either normalize (plain decode attention)
 or merge the current token's self-term analytically (the incremental form
-used inside the engine's layer scan, where the pool is read-only).
+used inside the models' decode scans, where the contiguous cache is
+read-only; the serving engine's paged pool is read by
+kernels/paged_attn.py).
 """
 from __future__ import annotations
 

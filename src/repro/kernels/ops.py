@@ -165,23 +165,24 @@ def _group_chunk_queries(q: jnp.ndarray, n_kv: int, cdt) -> jnp.ndarray:
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_state(
     q: jnp.ndarray,             # (B, T, H, Dh) — chunk queries, UNscaled
-    k_pool: jnp.ndarray,        # (n_blocks, block_size, KV, Dh)
+    k_pool: jnp.ndarray,        # (L, n_blocks, block_size, KV * Dh)
     v_pool: jnp.ndarray,
+    layer,                      # int32 scalar — the layer to read
     block_tables: jnp.ndarray,  # (B, max_blocks) int32
     ctx_lens: jnp.ndarray,      # (B,) int32 — cached context per slot
     *,
     interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Block-paged context attention (Pallas), returning online-softmax
-    state: (acc, m, l) f32 with acc (B, KV, T*rep, Dh) UNNORMALIZED and
-    m/l (B, KV, T*rep). Covers decode (T=1) and chunked prefill (T>1);
-    the caller merges the intra-chunk causal term
-    (models/common.chunk_attention_paged) before normalizing."""
-    n_kv = k_pool.shape[2]
+    """Block-paged context attention (Pallas) over one layer of the stacked
+    pool, returning online-softmax state: (acc, m, l) f32 with acc
+    (B, KV, T*rep, Dh) UNNORMALIZED and m/l (B, KV, T*rep). Covers decode
+    (T=1) and chunked prefill (T>1); the caller merges the intra-chunk
+    causal term (models/common.chunk_attention_paged) before normalizing."""
+    n_kv = k_pool.shape[-1] // q.shape[-1]
     qg = _group_chunk_queries(q, n_kv, k_pool.dtype)
     interp = _on_cpu() if interpret is None else interpret
     return paged_attn_pallas(
-        qg, k_pool, v_pool, block_tables.astype(jnp.int32),
+        qg, k_pool, v_pool, layer, block_tables.astype(jnp.int32),
         ctx_lens.astype(jnp.int32), interpret=interp)
 
 
@@ -189,22 +190,23 @@ def paged_attention_state_xla(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
+    layer,
     block_tables: jnp.ndarray,
     ctx_lens: jnp.ndarray,
     *,
     window: int | None = None,
     q_positions: jnp.ndarray | None = None,   # (B, T) abs positions (window)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """XLA-native equivalent (gather through the block table, same math and
-    dtype discipline) — the reference the kernel is tested against, and the
-    windowed-attention fallback."""
+    """XLA-native equivalent (one gather through the block table straight
+    from the stacked pool, same math and dtype discipline) — the reference
+    the kernel is tested against, and the windowed-attention fallback."""
     b, t, h, dh = q.shape
-    n_kv = k_pool.shape[2]
+    n_kv = k_pool.shape[-1] // dh
     qg = _group_chunk_queries(q, n_kv, k_pool.dtype)
     if q_positions is not None:
         q_positions = jnp.repeat(q_positions, h // n_kv, axis=1)   # (B, TR)
     return paged_attn_xla(
-        qg, k_pool, v_pool, block_tables.astype(jnp.int32),
+        qg, k_pool, v_pool, layer, block_tables.astype(jnp.int32),
         ctx_lens.astype(jnp.int32), window=window, q_positions=q_positions)
 
 

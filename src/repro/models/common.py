@@ -336,8 +336,9 @@ def decode_attention(
 
 def chunk_attention_paged(
     q: jnp.ndarray,             # (B, T, H, Dh) — this step's chunk queries
-    k_pool: jnp.ndarray,        # (n_blocks, block_size, KV, Dh) — READ-ONLY
+    k_pool: jnp.ndarray,        # (L, n_blocks, block_size, KV * Dh)
     v_pool: jnp.ndarray,
+    layer,                      # int32 scalar — the layer to read
     block_tables: jnp.ndarray,  # (B, max_blocks) int32; 0 = unmapped
     ctx_lens,                   # (B,) int32 — tokens already in the pool
     k_new: jnp.ndarray,         # (B, T, KV, Dh) — this chunk's K/V
@@ -345,7 +346,7 @@ def chunk_attention_paged(
     window: int | None = None,
     mode: ExecMode = ExecMode.XLA,
 ) -> jnp.ndarray:
-    """Mixed-batch attention over a block-paged KV pool, WITHOUT writing it.
+    """Mixed-batch attention of one layer over a block-paged KV pool.
 
     Chunk query t sits at absolute position ``ctx_lens[b] + t`` and splits
     its keys in two: (1) the CONTEXT — everything already in the pool, all
@@ -357,8 +358,12 @@ def chunk_attention_paged(
     the shared ``merge_attn_states`` — decode is exactly the T=1 case, so
     one code path serves prefilling and decoding slots in the same batch.
 
-    Keeping the pool read-only inside the layer scan preserves the
-    single-batched-scatter-per-step property (EXPERIMENTS.md §Perf).
+    The context is read from the stacked pool at ``layer`` directly (one
+    gather through the block tables, or the kernel's scalar-prefetched
+    index maps), so the caller can carry the whole pool through its layer
+    scan and write this chunk's rows after the call: rows at or past
+    ``ctx_len`` are masked here, and the chunk's own keys come from
+    ``k_new``/``v_new``, so the write never changes what this call reads.
 
     ``mode=ExecMode.PALLAS`` routes the context half to the paged Pallas
     kernel (global attention only); windowed callers and XLA mode share
@@ -372,11 +377,11 @@ def chunk_attention_paged(
     # --- context half: paged pool, uniform mask ------------------------------
     if mode == ExecMode.PALLAS and window is None:
         acc1, m1, l1 = ops.paged_attention_state(
-            q, k_pool, v_pool, block_tables, ctx)
+            q, k_pool, v_pool, layer, block_tables, ctx)
     else:
         q_pos = ctx[:, None] + jnp.arange(t) if window is not None else None
         acc1, m1, l1 = ops.paged_attention_state_xla(
-            q, k_pool, v_pool, block_tables, ctx,
+            q, k_pool, v_pool, layer, block_tables, ctx,
             window=window, q_positions=q_pos)
     # (B, KV, T*rep, ...) -> (B, KV, T, rep, ...)
     acc1 = acc1.reshape(b, n_kv, t, n_rep, dh)

@@ -20,13 +20,19 @@ The engine is split control-plane / data-plane (DESIGN.md §6):
     ``chunk_tokens`` lanes of a (n_slots, chunk_tokens) token batch —
     prefilling slots a chunk of their prompt, decoding slots their single
     last-sampled token — and the step embeds, runs a lax.scan over the
-    stacked layer weights with block-PAGED attention over the KV pool
-    (models/common.chunk_attention_paged), evaluates lm_head ONLY at each
-    slot's last valid lane, samples, scatters every new K/V row through the
-    block tables in ONE batched write, bumps per-slot lengths, and folds
-    the Algorithm 2 bitmap update into the same graph. Zero mid-step host
-    syncs; KV buffers are donated. Out-of-range scatter lanes land in the
-    pool's reserved dump block, so every write is unconditional and static.
+    stacked layer weights that CARRIES the whole K/V pool: each layer reads
+    its context with one block-PAGED gather straight from the stacked pool
+    (models/common.chunk_attention_paged), then writes its chunk's K/V rows
+    in place at ``[layer, blk, off]``. After the scan the step evaluates
+    lm_head ONLY at each slot's last valid lane, samples, bumps per-slot
+    lengths, and folds the Algorithm 2 bitmap update into the same graph.
+    Zero mid-step host syncs; KV buffers are donated. Invalid lanes write
+    to the pool's reserved dump block, so every write is unconditional and
+    static. The pool is lane-dense, ``(L, n_blocks, block, KV * Dh)``
+    (serving/kvcache.py), and passes through the scan as carry, never as a
+    scanned operand: with a 64-wide ``Dh`` minor dimension, or with the
+    pool sliced per layer as ``xs`` and scattered once after the scan, XLA
+    re-lays the whole pool out around the scatter every step.
   * control plane — the Python ``Engine``: a waiting->running admission
     queue (submit ENQUEUES; slots and worst-case block reservations are
     claimed at admission), per-step chunk planning under the Alg.2-coupled
@@ -69,7 +75,7 @@ from repro.models import common as cm
 from repro.models import dense
 from repro.models import moe as moe_mod
 from repro.serving import spec as spec_mod
-from repro.serving.kvcache import PagedKVPool
+from repro.serving.kvcache import PagedKVPool, row_slots, write_rows
 from repro.serving.prefix import PrefixIndex, block_hashes
 from repro.serving.sampler import SampleConfig, last_valid_hidden, sample
 
@@ -147,23 +153,26 @@ def _qkv(cfg, lp, fl, x, positions, bitmap):
 def _chunk_layer(cfg, exec_mode, bitmap, lengths, positions, block_tables,
                  x, layer, axis_name=None):
     """One mixed-batch layer over all slots' chunk lanes. ``layer`` =
-    (params slice, flash attn copy slice, read-only paged K/V pool slices).
-    The pool is never written here — the chunk's own K/V enters through the
-    intra-chunk causal term of chunk_attention_paged, so the scan stays
-    write-free and the step does ONE batched paged scatter after it.
+    (params slice, flash attn copy slice, the stacked K/V pools, layer
+    index). Attention reads this layer's context from the stacked pools
+    (rows before each slot's ``lengths``); the chunk's own K/V enters
+    through the intra-chunk causal term of chunk_attention_paged and is
+    returned, for the caller to write: the resident step writes it in the
+    scan right after this call, the streamed planes once per step in
+    ``_finish_step``.
 
     ``axis_name`` = tensor-parallel FFN (DESIGN.md §11): attention and the
     bitmap-dispatched projections run REPLICATED (every shard holds the
     DRAM tier and the attn flash copies whole), the FFN consumes the
     shard-LOCAL page tables and finishes with ONE psum."""
-    lp, fl, kc, vc = layer
+    lp, fl, k_pool, v_pool, li = layer
     ap = lp["attn"]
     b, t, _ = x.shape                                    # t == chunk_tokens
     with jax.named_scope("attn"):
         q, k, v = _qkv(cfg, lp, fl, x, positions, bitmap)
         with jax.named_scope("core"):
             attn = cm.chunk_attention_paged(
-                q, kc, vc, block_tables, lengths, k, v,
+                q, k_pool, v_pool, li, block_tables, lengths, k, v,
                 window=cfg.local_window, mode=exec_mode)
         with jax.named_scope("out"):
             out = _proj(attn.reshape(b, t, -1), ap["wo"], fl["wo"], bitmap)
@@ -174,20 +183,21 @@ def _chunk_layer(cfg, exec_mode, bitmap, lengths, positions, block_tables,
 
 
 def _moe_attn_router_body(cfg, exec_mode, lengths, positions, block_tables,
-                          x, lp, kc, vc):
+                          x, lp, k_pool, v_pool, li):
     """Attention + router for one MoE layer — the SINGLE definition both
     data planes compose (resident scan body and streamed router half), so
     the streamed-vs-resident parity the benchmark gates on holds by
     construction. MoE keeps Q/K/V/O on the NPU — the in-flash engine
     serves the EXPERT BANKS, the paper's best-fit case (DESIGN.md §9).
-    Returns the post-attention residual, the normed FFN input, the
-    router's (gates, idx), and the layer's fresh K/V."""
+    Attention reads layer ``li`` of the stacked pools. Returns the
+    post-attention residual, the normed FFN input, the router's
+    (gates, idx), and the layer's fresh K/V."""
     b, t, _ = x.shape
     with jax.named_scope("attn"):
         q, k, v = _qkv(cfg, lp, None, x, positions, None)
         with jax.named_scope("core"):
             attn = cm.chunk_attention_paged(
-                q, kc, vc, block_tables, lengths, k, v,
+                q, k_pool, v_pool, li, block_tables, lengths, k, v,
                 window=cfg.local_window, mode=exec_mode)
         with jax.named_scope("out"):
             out = _proj(attn.reshape(b, t, -1), lp["attn"]["wo"], None,
@@ -207,15 +217,17 @@ def _chunk_layer_moe(cfg, exec_mode, lengths, positions, block_tables,
     """One mixed-batch MoE layer (resident data plane): the shared
     attention+router body + the routed-only expert FFN over the deployed
     bank (``slab_map=None`` — the streamed expert half's degenerate case).
-    ``layer`` = (params slice without the expert bank, read-only paged K/V
-    pool slices, layer index); ``experts`` is the bank stacked over all
-    layers, indexed by the layer only where routed rows are read.
-    ``valid`` (slots, T) marks the lanes that carry a token — padding
-    lanes route nothing. Also returns the layer's valid assignments and
-    the distinct experts they route to (the step's MoE counters)."""
-    lp, kc, vc, li = layer
+    ``layer`` = (params slice without the expert bank, the stacked K/V
+    pools, layer index); ``experts`` is the bank stacked over all layers,
+    indexed by the layer only where routed rows are read. ``valid``
+    (slots, T) marks the lanes that carry a token — padding lanes route
+    nothing. Returns the layer's fresh K/V for the caller to write, as
+    ``_chunk_layer`` does, and the layer's valid assignments and the
+    distinct experts they route to (the step's MoE counters)."""
+    lp, k_pool, v_pool, li = layer
     x, h, gates, idx, k, v = _moe_attn_router_body(
-        cfg, exec_mode, lengths, positions, block_tables, x, lp, kc, vc)
+        cfg, exec_mode, lengths, positions, block_tables, x, lp, k_pool,
+        v_pool, li)
     with jax.named_scope("ffn"):
         y, counts = moe_mod.serve_expert_ffn(experts, h, gates, idx,
                                              valid=valid, layer=li,
@@ -236,7 +248,7 @@ def _moe_attn_router_impl(cfg, exec_mode, layers_dram, k_pool, v_pool, x,
 
     lp = jax.tree.map(sl, layers_dram)
     return _moe_attn_router_body(cfg, exec_mode, ctx_lens, positions,
-                                 block_tables, x, lp, sl(k_pool), sl(v_pool))
+                                 block_tables, x, lp, k_pool, v_pool, lo)
 
 
 def _moe_expert_impl(x, h, gates, idx, slab, slab_map):
@@ -371,8 +383,13 @@ def _finish_step(cfg, sched_cfg, sample_cfg, kv_aware, spec_k, final_norm,
                  positions, block_tables, key, drafts=None, n_draft=None,
                  is_decode=None):
     """Everything after the layer stack — final norm, last-lane sampling,
-    ONE batched paged KV scatter, in-graph Algorithm 2 — shared by the
-    monolithic and streamed data planes.
+    the length bump, in-graph Algorithm 2 — shared by the monolithic and
+    streamed data planes.
+
+    ``k_new``/``v_new`` (L, slots, T, KV, Dh) are the streamed planes'
+    rows, which carry no pool through a scan: they are written here in ONE
+    batched paged scatter for all layers. The resident step has written
+    its rows inside the layer scan already and passes None.
 
     ``spec_k`` (static) switches on the speculative verify tail: lm_head
     is additionally evaluated on the first ``spec_k + 1`` lanes of every
@@ -415,21 +432,15 @@ def _finish_step(cfg, sched_cfg, sample_cfg, kv_aware, spec_k, final_norm,
             n_emit = jnp.where(is_decode, n_accept + 1, 1).astype(jnp.int32)
             adv = jnp.where(is_decode, n_emit, q_lens)     # length REWIND
 
-    # --- paged KV scatter: ONE batched write for all layers/slots/lanes ------
-    with jax.named_scope("kv_write"):
-        block_size = state["k"].shape[2]
-        max_blocks = block_tables.shape[1]
-        lane = jnp.arange(positions.shape[1])[None, :]
-        pos = positions                                  # (slots, T)
-        valid = lane < q_lens[:, None]
-        blk_idx = jnp.clip(pos // block_size, 0, max_blocks - 1)
-        blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
-        # invalid lanes (and any unmapped table hit) land in the dump block 0
-        blk = jnp.where(valid, blk, 0)
-        off = jnp.where(valid, pos % block_size, 0)
-        kd = state["k"].at[:, blk, off].set(k_new.astype(state["k"].dtype))
-        vd = state["v"].at[:, blk, off].set(v_new.astype(state["v"].dtype))
-        new_lengths = lengths + adv
+    # --- streamed planes: ONE batched write for all layers/slots/lanes -----
+    kd, vd = state["k"], state["v"]
+    if k_new is not None:
+        with jax.named_scope("kv_write"):
+            blk, off = row_slots(positions, q_lens, block_tables,
+                                 kd.shape[2])
+            kd = write_rows(kd, slice(None), blk, off, k_new)
+            vd = write_rows(vd, slice(None), blk, off, v_new)
+    new_lengths = lengths + adv
 
     # --- Algorithm 2: KV-cache-aware rebalance, in-graph -------------------
     # admitted (not worked): a budget-starved prefill slot's cached KV
@@ -487,7 +498,7 @@ def _step_impl(cfg, sched_cfg, sample_cfg, kv_aware, exec_mode, unroll,
                draft_cap=None, is_decode=None):
     """One mixed prefill/decode step for ALL pool slots — the data plane.
 
-    state  : {"k","v": (L, n_blocks, block_size, KV, Dh),
+    state  : {"k","v": (L, n_blocks, block_size, KV * Dh),
               "lengths": (slots,) i32, "bitmap": (H,) i32,
               "prev_cycles": i32} — donated when jitted.
     tokens : (slots, T) i32 chunk lanes per slot (don't-care past q_lens).
@@ -500,10 +511,13 @@ def _step_impl(cfg, sched_cfg, sample_cfg, kv_aware, exec_mode, unroll,
     Returns (sampled (slots,) i32, new state, stats scalars) — or, with
     ``spec_k`` set, (tokens (slots, spec_k+1), n_emit, state, stats).
     Everything — drafting (spec), layer scan, paged attention, paged KV
-    scatter, length bump/rewind, Algorithm 2, sampling/verification — is
+    writes, length bump/rewind, Algorithm 2, sampling/verification — is
     one graph; idle slots compute garbage that is steered into the
     reserved dump block, so slot churn, ragged chunks, and admission churn
-    never change shapes or retrace.
+    never change shapes or retrace. The scan carries the K/V pools (module
+    docstring): each layer gathers its context from them, then writes its
+    chunk rows at ``[layer, blk, off]``, with ``(blk, off)`` computed once
+    a step.
     """
     bitmap = state["bitmap"] if kv_aware else None
     if spec_k is None:
@@ -514,6 +528,7 @@ def _step_impl(cfg, sched_cfg, sample_cfg, kv_aware, exec_mode, unroll,
         x, positions, ctx_lens, q_lens, drafts, n_draft = _embed_spec(
             cfg, proposer, spec_k, params, state["lengths"], tokens, q_lens,
             hist, hist_lens, draft_cap)
+    n_l = cfg.n_layers
     if cfg.family == "moe":
         # MoE projections stay on the NPU (no flash attn copy to dispatch
         # to), so the resident layer body drops the bitmap/flash operands.
@@ -522,34 +537,49 @@ def _step_impl(cfg, sched_cfg, sample_cfg, kv_aware, exec_mode, unroll,
         experts = layers["moe"]["experts"]
         layers = {**layers, "moe": {k: v for k, v in layers["moe"].items()
                                     if k != "experts"}}
-        body = functools.partial(_chunk_layer_moe, cfg, exec_mode, ctx_lens,
-                                 positions, block_tables, valid, experts)
-        xs = (layers, state["k"], state["v"], jnp.arange(cfg.n_layers))
+        layer_fn = functools.partial(_chunk_layer_moe, cfg, exec_mode,
+                                     ctx_lens, positions, block_tables,
+                                     valid, experts)
+        xs = (layers, jnp.arange(n_l))
     else:
-        body = functools.partial(_chunk_layer, cfg, exec_mode, bitmap,
-                                 ctx_lens, positions, block_tables)
-        xs = (params["layers"], attn_flash, state["k"], state["v"])
+        layer_fn = functools.partial(_chunk_layer, cfg, exec_mode, bitmap,
+                                     ctx_lens, positions, block_tables)
+        xs = (params["layers"], attn_flash, jnp.arange(n_l))
+    blk, off = row_slots(positions, q_lens, block_tables,
+                         state["k"].shape[2])
+
+    def body(carry, layer):
+        x, k_pool, v_pool = carry
+        *lp, li = layer
+        x, out = layer_fn(x, (*lp, k_pool, v_pool, li))
+        with jax.named_scope("kv_write"):
+            k_pool = write_rows(k_pool, li, blk, off, out[0])
+            v_pool = write_rows(v_pool, li, blk, off, out[1])
+        return (x, k_pool, v_pool), out[2:]
+
+    carry = (x, state["k"], state["v"])
     with jax.named_scope("layers"):
         if unroll:
             # eager reference: interpreted Python loop over layers
             outs = []
-            for li in range(cfg.n_layers):
-                x, out = body(x, jax.tree.map(lambda a: a[li], xs))
+            for li in range(n_l):
+                carry, out = body(carry, jax.tree.map(lambda a: a[li], xs))
                 outs.append(out)
             per_layer = jax.tree.map(lambda *a: jnp.stack(a), *outs)
         else:
-            x, per_layer = jax.lax.scan(body, x, xs)
-    k_new, v_new = per_layer[:2]                       # (L, S, T, KV, Dh)
+            carry, per_layer = jax.lax.scan(body, carry, xs)
+    x, k_pool, v_pool = carry
+    state = {**state, "k": k_pool, "v": v_pool}
 
     out = _finish_step(cfg, sched_cfg, sample_cfg, kv_aware, spec_k,
                        params["final_norm"], params["lm_head"], state, x,
-                       k_new, v_new, q_lens, admitted, positions,
+                       None, None, q_lens, admitted, positions,
                        block_tables, key, drafts=drafts, n_draft=n_draft,
                        is_decode=is_decode)
     if cfg.family == "moe":
         # summed over layers, read with the step's one stats transfer
-        out[-1]["moe_assignments"] = jnp.sum(per_layer[2])
-        out[-1]["moe_experts_routed"] = jnp.sum(per_layer[3])
+        out[-1]["moe_assignments"] = jnp.sum(per_layer[0])
+        out[-1]["moe_experts_routed"] = jnp.sum(per_layer[1])
     return out
 
 
@@ -584,10 +614,9 @@ def _stream_group_impl(cfg, exec_mode, kv_aware, group_size, shapes,
         return jax.lax.dynamic_slice_in_dim(a, lo, group_size, axis=0)
 
     lp_g = jax.tree.map(sl, layers_dram)
-    kc, vc = sl(k_pool), sl(v_pool)
 
     def body(x, layer):
-        lp_d, tf_ffn, tf_attn, kcl, vcl = layer
+        lp_d, tf_ffn, tf_attn, li = layer
         # graft the pool-paged flash FFN weights into the DRAM layer
         # params: the merged dict is exactly what the resident scan sees.
         lp = dict(lp_d)
@@ -597,12 +626,14 @@ def _stream_group_impl(cfg, exec_mode, kv_aware, group_size, shapes,
         fl_attn = {k: _paged(pool_buf, t, shapes["attn"][k])
                    for k, t in tf_attn.items()}
         return _chunk_layer(cfg, exec_mode, bm, ctx_lens, positions,
-                            block_tables, x, (lp, fl_attn, kcl, vcl),
+                            block_tables, x,
+                            (lp, fl_attn, k_pool, v_pool, li),
                             axis_name=axis_name)
 
     with jax.named_scope("layers"):
         x, (k_new, v_new) = jax.lax.scan(
-            body, x, (lp_g, window["ffn"], window["attn"], kc, vc))
+            body, x, (lp_g, window["ffn"], window["attn"],
+                      lo + jnp.arange(group_size)))
     return x, k_new, v_new
 
 

@@ -8,7 +8,11 @@ moves and the unit the paged-attention kernel streams.
 
 Layout (DESIGN.md §6):
 
-  * ``k`` / ``v``: ``(n_layers, n_blocks, block_size, KV, Dh)`` on device.
+  * ``k`` / ``v``: ``(n_layers, n_blocks, block_size, KV * Dh)`` on device.
+    The KV heads are folded into the minor dimension so it is lane-dense on
+    the TPU: a separate ``Dh`` = 64 minor dimension fills only half of a
+    128-lane tile, and XLA then lays the pool out with the block dimension
+    minor-most and re-lays the whole pool around every step's scatter.
     Block 0 is a RESERVED dump block — never allocated, never read (length
     masks exclude it); padded block-table entries and the compiled step's
     out-of-range scatter lanes land there, which keeps every write
@@ -42,6 +46,29 @@ def cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def row_slots(positions, q_lens, block_tables, block_size: int):
+    """Pool coordinates ``(blk, off)``, each (slots, T), of every chunk
+    lane's K/V row: lane ``t`` of slot ``b`` holds absolute position
+    ``positions[b, t]``. Lanes past ``q_lens`` (and any unmapped table hit)
+    go to the dump block 0, so a write through them is unconditional."""
+    lane = jnp.arange(positions.shape[1])[None, :]
+    valid = lane < q_lens[:, None]
+    blk_idx = jnp.clip(positions // block_size, 0, block_tables.shape[1] - 1)
+    blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
+    return (jnp.where(valid, blk, 0),
+            jnp.where(valid, positions % block_size, 0))
+
+
+def write_rows(pool, layer, blk, off, rows):
+    """Scatter chunk rows into the pool at ``[layer, blk, off]``.
+
+    ``rows`` is (slots, T, KV, Dh) for one ``layer`` (an index, traced or
+    not), or (L, slots, T, KV, Dh) with ``layer = slice(None)`` for every
+    layer at once."""
+    rows = rows.reshape(rows.shape[:-2] + (-1,)).astype(pool.dtype)
+    return pool.at[layer, blk, off].set(rows)
+
+
 @dataclasses.dataclass
 class PagedKVPool:
     n_layers: int
@@ -60,7 +87,7 @@ class PagedKVPool:
             self.n_blocks = self.n_slots * self.max_blocks + 1
         assert self.n_blocks >= 2, "need at least the dump block + one real"
         shape = (self.n_layers, self.n_blocks, self.block_size,
-                 self.n_kv_heads, self.head_dim)
+                 self.n_kv_heads * self.head_dim)
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
         self.lengths = np.zeros((self.n_slots,), np.int32)

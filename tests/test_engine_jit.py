@@ -4,6 +4,8 @@ across slot churn / chunked prefills / oversubscribed admission, and honor
 per-slot decode positions (the seed `positions[:1]` bug)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import numpy as np
 import pytest
@@ -12,7 +14,8 @@ from repro.configs.paper_models import OPT_TINY
 from repro.core.erdpe import ExecMode
 from repro.core.scheduler import AdmissionConfig
 from repro.models import dense
-from repro.serving.engine import Engine
+from repro.serving.engine import Engine, _step_impl
+from repro.serving.spec import SpecConfig
 
 
 @pytest.fixture(scope="module")
@@ -123,6 +126,85 @@ def test_realloc_matches_eager(params):
         res = eng.run()
         outs[compiled] = (res[r1], res[r2], res[r3])
     assert outs[True] == outs[False]
+
+
+def _written_rows(args, block_size):
+    """Every (block, offset) a step called with ``args`` may write outside
+    the dump block: each slot's lanes ``t < q_lens`` (plus its draft lanes,
+    up to ``draft_cap``, in a verify step) at position ``lengths + t``."""
+    _, _, state, _, q_lens, _, tables = args[:7]
+    lanes = np.asarray(q_lens).copy()
+    if len(args) > 8:                    # (hist, hist_lens, draft_cap, ...)
+        lanes += np.asarray(args[10])
+    lengths, tables = np.asarray(state["lengths"]), np.asarray(tables)
+    rows = set()
+    for b, n in enumerate(lanes):
+        for t in range(int(n)):
+            pos = int(lengths[b]) + t
+            rows.add((int(tables[b, pos // block_size]), pos % block_size))
+    return rows
+
+
+@pytest.mark.parametrize("spec_k", [None, 2], ids=["plain", "spec"])
+def test_compiled_step_matches_eager_pool_rows(params, spec_k):
+    """Over a mixed run (chunked prefill, decode, a slot released and
+    re-admitted, and with ``spec_k`` speculative verify steps), every step
+    of the compiled engine is replayed by the eager per-layer loop that
+    ``compiled=False`` runs: the same tokens, lengths and pool at every
+    real block. A step changes pool rows outside the dump block 0 only at
+    its valid lanes' positions."""
+    eng = _engine(params, True,
+                  admission_cfg=AdmissionConfig(chunk_tokens=8,
+                                                token_budget=16),
+                  spec_cfg=SpecConfig(k=spec_k) if spec_k else None)
+    eager = functools.partial(_step_impl, OPT_TINY, eng.sched_cfg,
+                              eng.sample_cfg, eng.kv_aware, ExecMode.XLA,
+                              True, eng.proposer, spec_k)
+    compiled = eng._step_fn
+    seen = {"continued_chunk": 0, "verify": 0}
+    bs = eng.pool.block_size
+
+    def both(*args):
+        got = compiled(*args)
+        want = eager(*args)
+        state_in, q_lens = args[2], np.asarray(args[4])
+        lengths = np.asarray(state_in["lengths"])
+        seen["continued_chunk"] += int(np.any((q_lens > 1) & (lengths > 0)))
+        if spec_k:
+            seen["verify"] += int(np.any(np.asarray(args[10]) > 0))
+        *toks_got, st_got, _ = got
+        *toks_want, st_want, _ = want
+        for a, b in zip(toks_got, toks_want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(st_got["lengths"]),
+                                      np.asarray(st_want["lengths"]))
+        allowed = _written_rows(args, bs)
+        for name in ("k", "v"):
+            new = np.asarray(st_got[name], np.float32)
+            np.testing.assert_array_equal(
+                new[:, 1:], np.asarray(st_want[name], np.float32)[:, 1:],
+                err_msg=f"pool {name}: compiled != eager")
+            old = np.asarray(state_in[name], np.float32)
+            changed = np.any(new != old, axis=-1)       # (L, blocks, rows)
+            _, blks, offs = np.nonzero(changed[:, 1:])
+            stray = {(int(b) + 1, int(o)) for b, o in zip(blks, offs)} \
+                - allowed
+            assert not stray, f"pool {name} written off the valid lanes"
+        return got
+
+    eng._step_fn = both
+    r1 = eng.submit([4, 4, 4], max_new=2)
+    r2 = eng.submit([5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6, 7,
+                     5, 6], max_new=12)                      # 3 chunks
+    while not eng.requests[r1].done:
+        eng.step()
+    r3 = eng.submit([2, 3, 2, 3, 2, 3, 2, 3, 2, 3], max_new=8)  # r1's slot
+    out = eng.run()
+    assert [len(out[r]) for r in (r1, r2, r3)] == [2, 12, 8]
+    assert eng.requests[r3].slot == eng.requests[r1].slot
+    assert seen["continued_chunk"] > 0, "no chunk continued a prefill"
+    if spec_k:
+        assert seen["verify"] > 0, "no verify step drafted"
 
 
 def test_pallas_decode_attention_end_to_end(params):

@@ -14,24 +14,32 @@ from repro.models import common as cm
 from repro.serving.kvcache import PagedKVPool
 
 
+N_LAYERS, LAYER = 3, 1       # the stacked pool's depth and the layer read
+
+
 def _scatter_to_pool(k_ctx, v_ctx, ctx_lens, block_size, max_blocks, seed=0):
-    """Scatter contiguous (B, S, KV, Dh) caches into a paged pool with a
-    SCRAMBLED block assignment (physical layout must not matter)."""
+    """Scatter contiguous (B, S, KV, Dh) caches into layer ``LAYER`` of a
+    stacked (L, n_blocks, block_size, KV * Dh) pool with a SCRAMBLED block
+    assignment (physical layout must not matter); every other layer and
+    every unmapped block holds garbage."""
     b, s, n_kv, dh = k_ctx.shape
     n_blocks = 1 + b * max_blocks
     rng = np.random.default_rng(seed)
     perm = rng.permutation(np.arange(1, n_blocks))
     tables = np.zeros((b, max_blocks), np.int32)
-    k_pool = rng.normal(size=(n_blocks, block_size, n_kv, dh))  # garbage fill
-    v_pool = rng.normal(size=(n_blocks, block_size, n_kv, dh))
+    shape = (N_LAYERS, n_blocks, block_size, n_kv * dh)
+    k_pool = rng.normal(size=shape)                     # garbage fill
+    v_pool = rng.normal(size=shape)
     pi = 0
     for i in range(b):
         for j in range(-(-int(ctx_lens[i]) // block_size)):
             blk = int(perm[pi]); pi += 1
             tables[i, j] = blk
             lo, hi = j * block_size, min((j + 1) * block_size, s)
-            k_pool[blk, :hi - lo] = np.asarray(k_ctx)[i, lo:hi]
-            v_pool[blk, :hi - lo] = np.asarray(v_ctx)[i, lo:hi]
+            k_pool[LAYER, blk, :hi - lo] = np.asarray(k_ctx)[i, lo:hi] \
+                .reshape(hi - lo, -1)
+            v_pool[LAYER, blk, :hi - lo] = np.asarray(v_ctx)[i, lo:hi] \
+                .reshape(hi - lo, -1)
     return (jnp.asarray(k_pool, k_ctx.dtype), jnp.asarray(v_pool, v_ctx.dtype),
             jnp.asarray(tables))
 
@@ -73,9 +81,10 @@ def test_paged_state_matches_contiguous_reference(b, t, h, n_kv, dh,
     l_ref = jnp.sum(p, axis=-1)
 
     for impl, (acc, m, l) in {
-        "xla": ops.paged_attention_state_xla(q, k_pool, v_pool, tables, ctx),
-        "pallas": ops.paged_attention_state(q, k_pool, v_pool, tables, ctx,
-                                            interpret=True),
+        "xla": ops.paged_attention_state_xla(q, k_pool, v_pool, LAYER,
+                                             tables, ctx),
+        "pallas": ops.paged_attention_state(q, k_pool, v_pool, LAYER, tables,
+                                            ctx, interpret=True),
     }.items():
         np.testing.assert_allclose(np.asarray(m), np.asarray(m_ref),
                                    rtol=1e-5, atol=1e-5, err_msg=impl)
@@ -96,8 +105,8 @@ def test_chunk_attention_matches_full_causal(mode, dtype):
                             dtype)
     ctx = jnp.asarray([0, 7, 24], jnp.int32)
     k_pool, v_pool, tables = _scatter_to_pool(kc, vc, ctx, bs, s // bs)
-    got = cm.chunk_attention_paged(q, k_pool, v_pool, tables, ctx, kn, vn,
-                                   mode=mode)
+    got = cm.chunk_attention_paged(q, k_pool, v_pool, LAYER, tables, ctx,
+                                   kn, vn, mode=mode)
     outs = []
     for i in range(b):
         c = int(ctx[i])
@@ -120,7 +129,8 @@ def test_decode_is_chunk_of_one():
     q, kc, vc, kn, vn = _mk(jax.random.PRNGKey(3), b, s, 1, h, n_kv, dh)
     ctx = jnp.asarray([5, 32], jnp.int32)
     k_pool, v_pool, tables = _scatter_to_pool(kc, vc, ctx, bs, s // bs)
-    got = cm.chunk_attention_paged(q, k_pool, v_pool, tables, ctx, kn, vn)
+    got = cm.chunk_attention_paged(q, k_pool, v_pool, LAYER, tables, ctx,
+                                   kn, vn)
     want = cm.decode_attention_incremental(q, kc, vc, ctx, kn, vn)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

@@ -71,9 +71,9 @@ def _ffn_paged_pallas(ecc_enabled):
 
 def _paged_attn():
     n_blocks = B * MAX_SEQ // BLOCK + 1                 # + the dump block
-    pool = ((n_blocks, BLOCK, KV, DH), jnp.bfloat16)
+    pool = ((2, n_blocks, BLOCK, KV * DH), jnp.bfloat16)  # two layers
     return (functools.partial(paged_attn_pallas, interpret=False),
-            [((B, KV, T, DH), jnp.bfloat16), pool, pool,
+            [((B, KV, T, DH), jnp.bfloat16), pool, pool, ((), jnp.int32),
              ((B, MAX_SEQ // BLOCK), jnp.int32), ((B,), jnp.int32)])
 
 
@@ -218,3 +218,91 @@ def test_moe_step_compiles_for_v5e_at_cell_sizes(one_chip):
         read[bound] = (cost[0] if isinstance(cost, list)
                        else cost)["bytes accessed"]
     assert read[32] < 0.5 * read[128], read
+
+
+# --- the resident step keeps the K/V pool in place -----------------------------
+
+def _resident_step(cfg, sharding, slots=4, max_seq=256):
+    """The resident ``_step_impl`` compiled as the engine jits it (donated
+    state, inline ECC), with its abstract K/V state."""
+    from repro.core import scheduler as sched
+    from repro.core.erdpe import ExecMode
+    from repro.core.tiering import deploy
+    from repro.models import dense, moe
+    from repro.serving import engine as eng_mod
+    from repro.serving.kvcache import PagedKVPool
+    from repro.serving.sampler import SampleConfig
+    S = jax.ShapeDtypeStruct
+    init = moe.init if cfg.family == "moe" else dense.init
+    raw = jax.eval_shape(lambda k: init(cfg, k), S((2,), jnp.uint32))
+    params = jax.eval_shape(lambda p: deploy(p)[0], raw)
+    attn_flash = None if cfg.family == "moe" else jax.eval_shape(
+        lambda p: eng_mod.Engine._flash_attn_copy(None, p, 0.0, 0), raw)
+    box = []
+
+    def kv():
+        box.append(PagedKVPool(cfg.n_layers, slots, max_seq, cfg.n_kv_heads,
+                               cfg.head_dim))
+        return box[0].device_state()
+    kv_state = jax.eval_shape(kv)
+    state = dict(kv_state, bitmap=S((32,), jnp.int32),
+                 prev_cycles=S((), jnp.int32))
+    chunk = sched.AdmissionConfig().chunk_tokens
+    args = (params, attn_flash, state, S((slots, chunk), jnp.int32),
+            S((slots,), jnp.int32), S((slots,), jnp.bool_),
+            S(box[0].block_tables.shape, jnp.int32), S((2,), jnp.uint32))
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=sharding),
+                        args)
+    step = functools.partial(
+        eng_mod._step_impl, cfg,
+        sched.SchedulerConfig(column_bytes=cfg.d_model, h=32),
+        SampleConfig(temperature=0.0), True, ExecMode.XLA, False, None, None)
+    compiled = eng_mod._jit(step, donate_argnums=(2,)).lower(*args).compile()
+    return compiled, kv_state["k"]
+
+
+def _bf16_ops(text: str):
+    """(op kind, element count) of every bfloat16 result in the HLO text."""
+    for m in re.finditer(r"%\S+ = bf16\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(",
+                         text):
+        n = 1
+        for d in filter(None, m.group(1).split(",")):
+            n *= int(d)
+        yield m.group(2), n
+
+
+def _pool_widths(name):
+    """The configuration at its published attention widths, cut to two
+    layers and a 4 x 256-position pool; Qwen3's expert count and
+    vocabulary are cut as well, which the pool never sees, to keep the
+    compile short."""
+    import dataclasses
+    from repro.configs.paper_models import OPT_1_3B
+    from repro.configs.qwen3_moe_30b_a3b import CONFIG
+    if name == "opt-1.3b":
+        return dataclasses.replace(OPT_1_3B, n_layers=2, max_seq=256)
+    return dataclasses.replace(CONFIG, n_layers=2, max_seq=256,
+                               n_experts=16, vocab_size=4096)
+
+
+@pytest.mark.parametrize("name", ["opt-1.3b", "qwen3-moe-30b-a3b"])
+def test_resident_step_writes_pool_in_place_for_v5e(name, one_chip):
+    """At the cells' attention widths (OPT: 32 KV heads of 64; Qwen3: 4 of
+    128; 16-row blocks) the compiled step neither copies the stacked pool
+    or a layer of it nor materialises a layer slice, and the donated pool
+    comes back unpadded: its bytes are its logical bytes."""
+    cfg = _pool_widths(name)
+    compiled, pool = _resident_step(cfg, one_chip)
+    assert pool.shape[-1] == cfg.n_kv_heads * cfg.head_dim
+    whole = pool.size
+    layer = whole // pool.shape[0]
+    ops_ = list(_bf16_ops(compiled.as_text()))
+    assert ops_, "no bfloat16 op parsed from the HLO"
+    copies = [(k, n) for k, n in ops_
+              if k in ("copy", "copy-start") and n in (whole, layer)]
+    assert not copies, f"pool-shaped copies: {copies}"
+    slices = [(k, n) for k, n in ops_ if k == "fusion" and n == layer]
+    assert not slices, f"fusions output a layer slice: {slices}"
+    pool_bytes = 2 * pool.size * jnp.dtype(pool.dtype).itemsize
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert pool_bytes <= out < pool_bytes + 2**16, (out, pool_bytes)
