@@ -139,10 +139,10 @@ def run() -> Report:
     # forced streamer-worker crash: the next TWO window fetches fail ->
     # the worker's in-fetch retry budget (1) exhausts -> typed StoreFault
     # -> the step faults -> the front's step retry re-runs it exactly.
-    eng.streamer.max_fetch_retries = 1
-    eng.streamer.retry_backoff_s = 0.001
+    eng.plane.streamer.max_fetch_retries = 1
+    eng.plane.streamer.retry_backoff_s = 0.001
     crash = {"left": 0}
-    orig_window = eng.streamer._window
+    orig_window = eng.plane.streamer._window
 
     def window(g):
         if crash["left"] > 0:
@@ -150,7 +150,7 @@ def run() -> Report:
             raise IOError("forced NAND channel crash")
         return orig_window(g)
 
-    eng.streamer._window = window
+    eng.plane.streamer._window = window
 
     # phase A: normal traffic; mid-phase, force the worker crash
     prompts = _dense_prompts()
@@ -183,7 +183,7 @@ def run() -> Report:
     health_code, health = front.health()
     st = front.stats()
     sstats = store.stats()
-    fstats = eng.streamer.stats()
+    fstats = eng.plane.streamer.stats()
     front.close()
 
     # --- MoE under chaos -----------------------------------------------------

@@ -86,8 +86,7 @@ def _serve(cfg, params, budget, n_shards, max_new):
                  stream_cfg=StreamConfig(device_budget_bytes=budget,
                                          n_shards=n_shards))
     got, tps = _run_engine(eng, max_new)
-    stats = (eng.expert_stats() if eng.streamed_moe
-             else eng.stream_stats())
+    stats = eng.expert_stats(strict=False) or eng.stream_stats()
     traces = eng.step_traces
     eng.close()
     return got, tps, stats, traces

@@ -27,12 +27,14 @@ import numpy as np                                          # noqa: E402
 
 from repro.core import scheduler as sched                   # noqa: E402
 from repro.core.erdpe import ExecMode                       # noqa: E402
-from repro.core.tiering import FlashWeight, deploy          # noqa: E402
+from repro.core.tiering import (ATTN_FLASH_KEYS, FlashWeight,  # noqa: E402
+                                deploy)
 from repro.launch.mesh import MODEL_AXIS                    # noqa: E402
 from repro.launch.serve import resolve_config               # noqa: E402
 from repro.launch.sharding import stream_window_specs, tp_shard_axis  # noqa
 from repro.models import dense                              # noqa: E402
-from repro.serving import engine as eng_mod                 # noqa: E402
+from repro.serving import step as step_mod                  # noqa: E402
+from repro.serving.engine import flash_attn_copy            # noqa: E402
 from repro.serving.kvcache import PagedKVPool               # noqa: E402
 from repro.serving.sampler import SampleConfig              # noqa: E402
 from repro.store.pagestore import PAGE_BYTES, TILE          # noqa: E402
@@ -66,11 +68,11 @@ def _kv_state(cfg):
 
 
 def resident_args(cfg):
-    """Abstract ``Engine._step_fn`` arguments (resident plane)."""
+    """Abstract ``ResidentPlane._step_fn`` arguments."""
     raw = _raw_params(cfg)
     params = jax.eval_shape(lambda p: deploy(p)[0], raw)
     attn_flash = jax.eval_shape(
-        lambda p: eng_mod.Engine._flash_attn_copy(None, p, 0.0, 0), raw)
+        lambda p: flash_attn_copy(p, 0.0, 0), raw)
     kv, bt_shape = _kv_state(cfg)
     state = dict(kv, bitmap=S((_sched_cfg(cfg).h,), jnp.int32),
                  prev_cycles=S((), jnp.int32))
@@ -94,7 +96,7 @@ def _local_kn(kn, axis, shards):
 
 
 def group_args(cfg, n_pages, shards=1):
-    """Abstract ``Engine._group_fn`` arguments (dense streamed plane) and
+    """Abstract ``StreamedPlane._group_fn`` arguments and
     the window's static (K, N) shapes — shard-LOCAL under ``shards``, with
     ``n_pages`` pool rows per shard."""
     raw = _raw_params(cfg)
@@ -107,7 +109,7 @@ def group_args(cfg, n_pages, shards=1):
                       for n, w in layers["ffn"].items()
                       if isinstance(w, FlashWeight)},
               "attn": {n: tuple(raw["layers"]["attn"][n].shape[1:])
-                       for n in eng_mod.ATTN_FLASH_KEYS}}
+                       for n in ATTN_FLASH_KEYS}}
     window = {part: {n: _page_table(*kn)
                      for n, kn in shapes[part].items()} for part in shapes}
     kv, bt_shape = _kv_state(cfg)
@@ -138,19 +140,19 @@ def check_against_engine():
 
     cfg = resolve_config("opt-tiny")
     eng = build_engine("opt-tiny", **kw)
-    eng._step_fn = spy("step", eng._step_fn)
+    eng.plane._step_fn = spy("step", eng.plane._step_fn)
     eng.submit([1, 2, 3], max_new=1)
     eng.run()
     want = _signature(resident_args(cfg))
     assert _signature(seen["step"]) == want, "resident args drifted"
     eng = build_engine("opt-tiny", stream=True, **kw)
-    eng._group_fn = spy("group", eng._group_fn)
+    eng.plane._group_fn = spy("group", eng.plane._group_fn)
     eng.submit([1, 2, 3], max_new=1)
     eng.run()
     got = seen["group"]
     args, shapes = group_args(cfg, got[2].shape[0])
     assert _signature(got) == _signature(args), "group args drifted"
-    assert shapes == eng._win_shapes, "window shapes drifted"
+    assert shapes == eng.plane._win_shapes, "window shapes drifted"
 
 
 def _on(sharding, tree):
@@ -189,13 +191,13 @@ def main():
           f"d_ff {cfg.d_ff}, vocab {cfg.vocab_size} -> "
           f"{topo.devices[0].device_kind}")
     step = functools.partial(
-        eng_mod._step_impl, cfg, _sched_cfg(cfg), SampleConfig(), False,
+        step_mod._step_impl, cfg, _sched_cfg(cfg), SampleConfig(), False,
         ExecMode.XLA, False, None, None)
     compile_for_chip("resident step", step, _on(chip, resident_args(cfg)),
                      donate=(2,))
     n_pages = POOL_MIB * 2**20 // PAGE_BYTES
     gargs, shapes = group_args(cfg, n_pages)
-    group = functools.partial(eng_mod._stream_group_impl, cfg, ExecMode.XLA,
+    group = functools.partial(step_mod._stream_group_impl, cfg, ExecMode.XLA,
                               False, 1, shapes)
     compile_for_chip("streamed group", group, _on(chip, gargs))
     if args.shards:
@@ -204,7 +206,7 @@ def main():
 
 def compile_sharded_group(cfg, topo, shards, n_pages):
     """The streamed group under ``shard_map`` over a "model" mesh of
-    described chips, exactly as ``Engine._build_stream_fns`` wraps it."""
+    described chips, exactly as ``StreamedPlane._build_programs`` wraps it."""
     from jax.sharding import Mesh, NamedSharding
     mesh = Mesh(np.array(topo.devices[:shards]), (MODEL_AXIS,))
     specs = stream_window_specs(mesh)
@@ -214,7 +216,7 @@ def compile_sharded_group(cfg, topo, shards, n_pages):
     gargs = tuple(_on(NamedSharding(mesh, sp), a)
                   for sp, a in zip(in_specs, gargs))
     group = jax.shard_map(
-        functools.partial(eng_mod._stream_group_impl, cfg, ExecMode.XLA,
+        functools.partial(step_mod._stream_group_impl, cfg, ExecMode.XLA,
                           False, 1, shapes, axis_name=MODEL_AXIS),
         mesh=mesh, in_specs=in_specs, out_specs=rspec, check_vma=False)
     compile_for_chip(f"streamed group x{shards} shards", group, gargs,

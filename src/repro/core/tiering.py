@@ -255,7 +255,7 @@ def program_attn_flash(store: Any, attn_layers: Any, n_layers: int,
                        rber: float = 0.0, seed: int = 0) -> None:
     """Program the per-layer attn flash copies into ``store`` under
     ``attn_flash/{key}@{layer}`` — numerically identical to the resident
-    engine's ``_flash_attn_copy`` tier (same quant/parity/RBER seeds)."""
+    plane's ``flash_attn_copy`` tier (same quant/parity/RBER seeds)."""
     for li in range(n_layers):
         for k in ATTN_FLASH_KEYS:
             store.put(f"attn_flash/{k}@{li}",

@@ -178,12 +178,12 @@ def build_engine(arch: str = "opt-tiny", smoke: bool = False,
                  draft_params=draft_params, prefix_cache=prefix_cache,
                  max_waiting=max_waiting, admission_cfg=admission_cfg)
     if fault_cfg is not None:
-        if not eng.streamed:
+        if eng.plane.store is None:
             raise SystemExit("--fault-* injects read-time NAND faults: "
                              "they need the streamed page store (add "
                              "--stream or --store-image)")
         from repro.store.faults import FaultInjector
-        eng.store.attach_injector(FaultInjector(fault_cfg))
+        eng.plane.store.attach_injector(FaultInjector(fault_cfg))
     return eng
 
 
@@ -272,10 +272,10 @@ def serve(arch: str = "opt-tiny", smoke: bool = False, n_requests: int = 6,
            "processed_tps": n_processed / max(dt, 1e-9),
            "stats": eng.stats,
            "ttft_steps": first_tok, "traces": eng.step_traces}
-    if eng.streamed:
-        out["stream"] = eng.stream_stats()
-        if eng.streamed_moe:
-            out["experts"] = eng.expert_stats()
+    for key, stats in (("stream", eng.stream_stats(strict=False)),
+                       ("experts", eng.expert_stats(strict=False))):
+        if stats:
+            out[key] = stats
     if spec_k > 0:
         out["spec"] = eng.spec_stats()
     eng.close()

@@ -377,8 +377,9 @@ class ServeFront:
         stream = eng.stream_stats(strict=False)
         if stream:
             out["stream"] = stream
-            if getattr(eng, "streamed_moe", False):
-                out["experts"] = eng.expert_stats(strict=False)
+        experts = eng.expert_stats(strict=False)
+        if experts:
+            out["experts"] = experts
         spec = eng.spec_stats(strict=False)
         if spec:
             out["spec"] = spec

@@ -14,7 +14,8 @@ from repro.configs.paper_models import OPT_TINY
 from repro.core.erdpe import ExecMode
 from repro.core.scheduler import AdmissionConfig
 from repro.models import dense
-from repro.serving.engine import Engine, _step_impl
+from repro.serving.engine import Engine
+from repro.serving.step import _step_impl
 from repro.serving.spec import SpecConfig
 
 
@@ -160,7 +161,7 @@ def test_compiled_step_matches_eager_pool_rows(params, spec_k):
     eager = functools.partial(_step_impl, OPT_TINY, eng.sched_cfg,
                               eng.sample_cfg, eng.kv_aware, ExecMode.XLA,
                               True, eng.proposer, spec_k)
-    compiled = eng._step_fn
+    compiled = eng.plane._step_fn
     seen = {"continued_chunk": 0, "verify": 0}
     bs = eng.pool.block_size
 
@@ -192,7 +193,7 @@ def test_compiled_step_matches_eager_pool_rows(params, spec_k):
             assert not stray, f"pool {name} written off the valid lanes"
         return got
 
-    eng._step_fn = both
+    eng.plane._step_fn = both
     r1 = eng.submit([4, 4, 4], max_new=2)
     r2 = eng.submit([5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6, 7,
                      5, 6], max_new=12)                      # 3 chunks

@@ -15,7 +15,6 @@ from repro.configs.base import ArchConfig
 from repro.core import ecc
 from repro.core.tiering import FlashWeight, deploy, encode_flash
 from repro.models import moe
-from repro.serving import engine as eng_mod
 from repro.serving.engine import Engine
 from repro.serving.sampler import SampleConfig
 
@@ -190,7 +189,7 @@ def test_moe_counters_equal_host_recount(params, monkeypatch):
         seen.append((np.asarray(idx), np.asarray(kw["valid"])))
         return real(bank, x, gates, idx, slab_map, **kw)
 
-    monkeypatch.setattr(eng_mod.moe_mod, "serve_expert_ffn", spy)
+    monkeypatch.setattr(moe, "serve_expert_ffn", spy)
     reg = obs.MetricsRegistry()
     _serve(params, compiled=False, registry=reg)
     assert seen

@@ -143,7 +143,7 @@ def test_streamed_under_budget_smaller_than_flash_tier(params,
     assert st["expert_bytes_fetched"] > 0
     assert st["expert_bytes_per_token"] < st["all_experts_bytes_per_token"]
     # the cache respects its residual capacity at all times
-    assert eng.expert_cache.bytes_used <= eng.expert_cache.capacity
+    assert eng.plane.expert_cache.bytes_used <= eng.plane.expert_cache.capacity
 
 
 def test_streamed_pin_all_matches_resident(params, resident_tokens):
@@ -192,7 +192,7 @@ def test_preprogrammed_image_serves(params, resident_tokens, tmp_path):
     opened = PageStore.open(img)
     eng = Engine(CFG, dram_tier(params), max_slots=2, max_seq=MAX_SEQ,
                  weight_store=opened, stream_cfg=StreamConfig())
-    assert eng.store_preprogrammed
+    assert eng.plane.store_preprogrammed
     _submit_pair(eng)
     assert eng.run() == resident_tokens
 
@@ -205,12 +205,12 @@ def test_expert_stats_requires_moe_stream(params):
 
 def test_close_stops_prefetch_worker(params):
     """close() joins the prefetch worker (its fetch closure pins the
-    engine, so nothing is reclaimed without it) and is idempotent —
+    plane, so nothing is reclaimed without it) and is idempotent —
     including on engines that never had a prefetcher."""
     eng, _ = _streamed(params)
     eng.submit([1, 2, 3], max_new=2)
     eng.run()
-    worker = eng.prefetcher._thread
+    worker = eng.plane.prefetcher._thread
     eng.close()
     assert not worker.is_alive()
     eng.close()                                  # idempotent
@@ -267,8 +267,8 @@ def test_streamed_pin_shared_experts(params, resident_tokens):
     assert eng.run() == resident_tokens
     for li in range(CFG.n_layers):
         for e in range(2):
-            assert (li, e) in eng.expert_cache
-            assert eng.expert_cache._entries[(li, e)].pinned
+            assert (li, e) in eng.plane.expert_cache
+            assert eng.plane.expert_cache._entries[(li, e)].pinned
 
 
 def test_streamed_per_slot_stats(params):
@@ -295,28 +295,28 @@ def test_auto_expert_budget_returns_dead_slab_rows(params):
     budget = int(probe.total_bytes * 0.8)
     eng, _ = _streamed(params, device_budget_bytes=budget,
                        auto_expert_budget=True, auto_depth_after=2)
-    cap0 = eng.expert_cache.capacity
+    cap0 = eng.plane.expert_cache.capacity
     # drive the mechanism deterministically (the end-to-end flag is
     # covered below): observed routing used 3 of e_slab rows, and at
     # least one misroute stalled
-    eng._steps_done = 5
-    eng._max_routed_seen = 3
-    eng.expert_cache.note_stall(0.001)
-    eng._maybe_retune_expert_budget()
-    assert eng._auto_expert_done
-    grown = (eng._e_slab - 3) * eng._max_expert_bytes
-    assert eng.expert_cache.capacity == cap0 + grown
-    eng._maybe_retune_expert_budget()            # one-shot: no double-grow
-    assert eng.expert_cache.capacity == cap0 + grown
+    eng.plane._max_routed_seen = 3
+    eng.plane.expert_cache.note_stall(0.001)
+    eng.plane.after_step(5)                      # 5 steps done
+    assert eng.plane._auto_expert_done
+    grown = (eng.plane._e_slab - 3) * eng.plane._max_expert_bytes
+    assert eng.plane.expert_cache.capacity == cap0 + grown
+    eng.plane.after_step(6)                      # one-shot: no double-grow
+    assert eng.plane.expert_cache.capacity == cap0 + grown
     # end-to-end: the flag flips during a real run and serving still works
     eng2, _ = _streamed(params, device_budget_bytes=budget,
                         auto_expert_budget=True, auto_depth_after=2)
     _submit_pair(eng2)
     eng2.run()
     assert eng2.expert_stats()["expert_budget_retuned"]
-    if eng2.expert_cache.capacity != cap0:       # retune actually fired
-        assert eng2.expert_cache.capacity > cap0
-        assert eng2.expert_cache.bytes_used <= eng2.expert_cache.capacity
+    cache = eng2.plane.expert_cache
+    if cache.capacity != cap0:                   # retune actually fired
+        assert cache.capacity > cap0
+        assert cache.bytes_used <= cache.capacity
 
 
 def test_spec_streamed_moe_parity(params):

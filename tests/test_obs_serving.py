@@ -94,7 +94,7 @@ def _step_args(eng):
     n, t = eng.pool.n_slots, eng.admission_cfg.chunk_tokens
     state = dict(eng.pool.device_state(), bitmap=eng.bitmap,
                  prev_cycles=eng._prev_cycles)
-    return (eng.params, eng.attn_flash, state,
+    return (eng.params, eng.plane.attn_flash, state,
             jnp.zeros((n, t), jnp.int32), jnp.ones((n,), jnp.int32),
             jnp.ones((n,), bool), eng.pool.block_tables_dev(),
             jax.random.PRNGKey(0))
@@ -103,7 +103,7 @@ def _step_args(eng):
 def test_compiled_step_names_every_scope(params, monkeypatch):
     monkeypatch.setenv("REPRO_SERVE_ECC", "inline")
     eng = _engine(params)
-    hlo = eng._step_fn.lower(*_step_args(eng)).compile().as_text()
+    hlo = eng.plane._step_fn.lower(*_step_args(eng)).compile().as_text()
     assert "jit_serve_step" in hlo.splitlines()[0]
     paths = set()
     for chunk in hlo.split('op_name="')[1:]:
@@ -126,7 +126,7 @@ def test_submit_wait_covers_a_step_holding_the_engine_lock(params):
     reg = obs.MetricsRegistry()
     eng = _engine(params, registry=reg)
     stepping, released = threading.Event(), []
-    real_step_fn = eng._step_fn
+    real_step_fn = eng.plane._step_fn
 
     def slow_step(*args):
         out = real_step_fn(*args)
@@ -135,7 +135,7 @@ def test_submit_wait_covers_a_step_holding_the_engine_lock(params):
         released.append(time.perf_counter())
         return out
 
-    eng._step_fn = slow_step
+    eng.plane._step_fn = slow_step
     front = ServeFront(eng, registry=reg)
     try:
         first = front.add_request(list(range(1, 12)), max_new=3)

@@ -282,6 +282,26 @@ def test_stress_streamed_moe(moe_params):
     assert eng.step_traces == 3          # churn + cancels never retrace
 
 
+@pytest.mark.parametrize("plane,families", [
+    ("resident", set()), ("streamed", {"stream"}),
+    ("expert_paged", {"stream", "experts"})])
+def test_stats_nest_the_plane_families(params, moe_params, plane, families):
+    """/v1/stats nests exactly the stat families the engine's plane has."""
+    if plane == "resident":
+        eng = _engine(params)
+    elif plane == "streamed":
+        eng = _streamed(params)
+    else:
+        eng = Engine(MOE_CFG, moe_params, max_slots=2, max_seq=MAX_SEQ,
+                     weight_store=PageStore(n_planes=8),
+                     stream_cfg=StreamConfig())
+    front = ServeFront(eng)
+    try:
+        assert families == {"stream", "experts"} & set(front.stats())
+    finally:
+        front.close()
+
+
 def test_stress_prefix_cache_on(params):
     eng = _engine(params, prefix_cache=True)
     _stress(eng)

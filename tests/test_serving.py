@@ -157,7 +157,7 @@ def test_engine_rber_still_decodes():
     is_fw = lambda x: hasattr(x, "parity")
     flat = lambda e: (
         [l for l in jax.tree.leaves(e.params, is_leaf=is_fw) if is_fw(l)]
-        + [l for l in jax.tree.leaves(e.attn_flash, is_leaf=is_fw)
+        + [l for l in jax.tree.leaves(e.plane.attn_flash, is_leaf=is_fw)
            if is_fw(l)])
     for c, n in zip(flat(clean), flat(noisy)):
         qc = jnp.asarray(c.q).reshape(-1, c.q.shape[-1])

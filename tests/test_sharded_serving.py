@@ -78,8 +78,12 @@ def test_shard_entry_partitions_pages(kt, nt, s, seed):
     assert all(len(p) == len(plan.q_pages[0]) for p in plan.q_pages)
     # byte runs follow their tiles
     comp = store.table["w"]
-    assert plan.parity_nbytes * s == comp["parity"].nbytes
-    assert plan.scale_nbytes * s == comp["scale"].nbytes
+
+    def nbytes(c):
+        return int(np.prod(c.shape)) * np.dtype(c.dtype).itemsize
+
+    assert plan.parity_nbytes * s == nbytes(comp["parity"])
+    assert plan.scale_nbytes * s == nbytes(comp["scale"])
     # host slices reassemble the full parity run: tile column c of the
     # full array is local column c // s on shard c % s (round-robin)
     slices = store.shard_host_slices("w", plan)

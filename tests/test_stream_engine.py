@@ -93,8 +93,9 @@ def test_streamed_hot_pins(params):
     eng, _ = _streamed(params, group_size=1)     # unbounded budget
     rid = eng.submit([3, 1, 4], max_new=4)
     eng.run()
-    assert "lm_head" in eng.cache
-    assert 0 in eng.cache and eng.n_groups - 1 in eng.cache
+    cache = eng.plane.cache
+    assert "lm_head" in cache
+    assert 0 in cache and eng.plane.n_groups - 1 in cache
     st = eng.stream_stats()
     assert st["cache_hits"] > 0                  # pinned edges re-used
     assert len(eng.requests[rid].out) == 4
@@ -160,22 +161,23 @@ def test_auto_depth_retunes_prefetch_from_telemetry(params):
                        auto_depth_after=3)
     eng.submit(list(range(1, 20)), max_new=12)
     eng.run()
-    assert eng._auto_depth_done, "auto-tune never ran"
-    depth = eng.streamer.prefetch_depth
+    pl = eng.plane
+    assert pl._auto_depth_done, "auto-tune never ran"
+    depth = pl.streamer.prefetch_depth
     assert depth >= 1
-    if eng.stream_cfg.device_budget_bytes is not None:
-        afford = (budget - eng.cache.pinned_bytes) // eng._group_bytes
+    if pl.stream_cfg.device_budget_bytes is not None:
+        afford = (budget - pl.cache.pinned_bytes) // pl._group_bytes
         assert depth <= max(afford, 1)
         # budget re-split: window bytes + cache capacity never exceed it
-        if not eng.stream_cfg.pin_all and depth != 1:
-            assert eng.cache.capacity + depth * eng._group_bytes <= budget \
-                or eng.cache.capacity == eng.cache.pinned_bytes
+        if not pl.stream_cfg.pin_all and depth != 1:
+            assert pl.cache.capacity + depth * pl._group_bytes <= budget \
+                or pl.cache.capacity == pl.cache.pinned_bytes
         # and RESIDENT bytes were trimmed to the new capacity eagerly —
         # a deeper window reclaims its bytes at retune time, not at some
         # future insert (the device budget holds at every moment)
-        if eng.cache.capacity is not None:
-            assert eng.cache.bytes_used <= max(eng.cache.capacity,
-                                               eng.cache.pinned_bytes)
+        if pl.cache.capacity is not None:
+            assert pl.cache.bytes_used <= max(pl.cache.capacity,
+                                              pl.cache.pinned_bytes)
     # parity is untouched by depth choices (greedy, same prompts)
     ref = Engine(OPT_TINY, params, max_slots=2, max_seq=MAX_SEQ, rber=0.0)
     rid = ref.submit(list(range(1, 20)), max_new=12)
@@ -198,7 +200,7 @@ def test_serve_from_persisted_die_image(params, resident_tokens, tmp_path):
     opened = PageStore.open(img)
     eng = Engine(OPT_TINY, dram_tier(params), max_slots=2, max_seq=MAX_SEQ,
                  weight_store=opened, stream_cfg=StreamConfig(group_size=1))
-    assert eng.store_preprogrammed
+    assert eng.plane.store_preprogrammed
     assert opened.n_pages == store.n_pages        # nothing was programmed
     eng.submit(list(range(1, 30)), max_new=8)
     eng.submit([9, 8], max_new=8)
@@ -228,8 +230,8 @@ def test_transient_fetch_failure_recovers_with_token_parity(params,
     the streamer worker with backoff — serving completes with tokens
     bit-identical to the fault-free run, and the retry is counted."""
     eng, _ = _streamed(params, group_size=1)
-    eng.streamer.retry_backoff_s = 0.001
-    orig = eng.streamer._window
+    eng.plane.streamer.retry_backoff_s = 0.001
+    orig = eng.plane.streamer._window
     state = {"calls": 0}
 
     def flaky(g):
@@ -238,11 +240,11 @@ def test_transient_fetch_failure_recovers_with_token_parity(params,
             raise IOError("injected transient channel fault")
         return orig(g)
 
-    eng.streamer._window = flaky
+    eng.plane.streamer._window = flaky
     eng.submit(list(range(1, 30)), max_new=8)
     eng.submit([9, 8], max_new=8)
     assert eng.run() == resident_tokens      # no token divergence
-    st = eng.streamer.stats()
+    st = eng.plane.streamer.stats()
     assert st["fetch_retries"] == 1 and st["fetch_faults"] == 0
 
 
@@ -255,19 +257,19 @@ def test_persistent_fetch_failure_raises_typed_storefault(params):
     from repro.store.faults import StoreFault
 
     eng, _ = _streamed(params, group_size=1)
-    eng.streamer.retry_backoff_s = 0.001
-    eng.streamer.max_fetch_retries = 1
+    eng.plane.streamer.retry_backoff_s = 0.001
+    eng.plane.streamer.max_fetch_retries = 1
 
     def dead(g):
         raise IOError("dead channel")
 
     eng.submit([1, 2, 3], max_new=2)
-    eng.streamer._window = dead
+    eng.plane.streamer._window = dead
     with pytest.raises(StoreFault) as ei:
         eng.step()
     assert isinstance(ei.value.__cause__, IOError)
-    assert eng.streamer.stats()["fetch_faults"] == 1
-    assert eng.streamer.stats()["fetch_retries"] == 1
+    assert eng.plane.streamer.stats()["fetch_faults"] == 1
+    assert eng.plane.streamer.stats()["fetch_retries"] == 1
     t = threading.Thread(target=eng.close)   # must not hang on the queue
     t.start()
     t.join(timeout=30)

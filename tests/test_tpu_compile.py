@@ -191,13 +191,13 @@ def test_moe_step_compiles_for_v5e_at_cell_sizes(one_chip):
     whole bank's."""
     from repro.core.erdpe import ExecMode
     from repro.models import moe
-    from repro.serving import engine as eng_mod
+    from repro.serving import step as step_mod
     from repro.serving.sampler import SampleConfig
     cfg, sched_cfg, args = _moe_step_args(one_chip)
-    step = functools.partial(eng_mod._step_impl, cfg, sched_cfg,
+    step = functools.partial(step_mod._step_impl, cfg, sched_cfg,
                              SampleConfig(), True, ExecMode.XLA, False, None,
                              None)
-    compiled = eng_mod._jit(step, donate_argnums=(2,)).lower(*args) \
+    compiled = step_mod._jit(step, donate_argnums=(2,)).lower(*args) \
         .compile()
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 8 * 2**30
@@ -229,7 +229,8 @@ def _resident_step(cfg, sharding, slots=4, max_seq=256):
     from repro.core.erdpe import ExecMode
     from repro.core.tiering import deploy
     from repro.models import dense, moe
-    from repro.serving import engine as eng_mod
+    from repro.serving import step as step_mod
+    from repro.serving.engine import flash_attn_copy
     from repro.serving.kvcache import PagedKVPool
     from repro.serving.sampler import SampleConfig
     S = jax.ShapeDtypeStruct
@@ -237,7 +238,7 @@ def _resident_step(cfg, sharding, slots=4, max_seq=256):
     raw = jax.eval_shape(lambda k: init(cfg, k), S((2,), jnp.uint32))
     params = jax.eval_shape(lambda p: deploy(p)[0], raw)
     attn_flash = None if cfg.family == "moe" else jax.eval_shape(
-        lambda p: eng_mod.Engine._flash_attn_copy(None, p, 0.0, 0), raw)
+        lambda p: flash_attn_copy(p, 0.0, 0), raw)
     box = []
 
     def kv():
@@ -254,10 +255,10 @@ def _resident_step(cfg, sharding, slots=4, max_seq=256):
     args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=sharding),
                         args)
     step = functools.partial(
-        eng_mod._step_impl, cfg,
+        step_mod._step_impl, cfg,
         sched.SchedulerConfig(column_bytes=cfg.d_model, h=32),
         SampleConfig(temperature=0.0), True, ExecMode.XLA, False, None, None)
-    compiled = eng_mod._jit(step, donate_argnums=(2,)).lower(*args).compile()
+    compiled = step_mod._jit(step, donate_argnums=(2,)).lower(*args).compile()
     return compiled, kv_state["k"]
 
 
